@@ -54,6 +54,7 @@ from repro.data.pipeline import TokenPipeline
 from repro.distributed.context import DistContext
 from repro.kernels import digest as kdigest
 from repro.launch.elastic import ElasticManager
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import bind_state
 from repro.train.loop import make_train_state, make_train_step
 
@@ -83,7 +84,7 @@ def run(*, arch: str = "iterpro-100m", smoke: bool = True,
             cfg, sharding=dataclasses.replace(cfg.sharding, fsdp=True))
     B, S = global_batch, seq_len
 
-    ctx = DistContext.for_mesh(jax.make_mesh((4, 2), ("data", "model")))
+    ctx = DistContext.for_mesh(make_mesh((4, 2), ("data", "model")))
     pipe = TokenPipeline(cfg.model.vocab_size, S, B, seed=seed)
     state = make_train_state(cfg, jax.random.PRNGKey(seed), global_batch=B)
     raw_bfn = lambda s: pipe.batch_at(s)

@@ -29,6 +29,7 @@ from repro.core import ChecksumCanary, MicroCheckpointer, trap_loss_spike, trap_
 from repro.core.detect import LOSS_WINDOW
 from repro.kernels import digest as kdigest
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_mesh
 
 
 def _loop(campaign: Campaign, steps: int, *, traps: bool, canary_k: int,
@@ -368,9 +369,9 @@ def sharded_steady_state(campaign: Campaign, steps: int = 10,
         raw = campaign.raw_step()
     else:
         if n_dev >= 4 and n_dev % 2 == 0:
-            mesh = jax.make_mesh((n_dev // 2, 2), ("data", "model"))
+            mesh = make_mesh((n_dev // 2, 2), ("data", "model"))
         else:
-            mesh = jax.make_mesh((n_dev,), ("data",))
+            mesh = make_mesh((n_dev,), ("data",))
         ctx = DistContext.for_mesh(mesh)
         from repro.launch.specs import bind_state
         state, raw, bfn, _ = bind_state(

@@ -31,6 +31,7 @@ from repro.core.recover import RecoveryRuntime
 from repro.data.pipeline import TokenPipeline
 from repro.distributed.context import DistContext
 from repro.kernels import digest as kdigest
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import bind_state
 from repro.train.loop import (
     make_train_state,
@@ -43,7 +44,7 @@ def main():
         "run with XLA_FLAGS=--xla_force_host_platform_device_count=8")
     cfg = get_config("iterpro-100m").smoke()
     B, S = 8, 32
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ctx = DistContext.for_mesh(mesh)
     print(f"mesh: {dict(mesh.shape)} -> {ctx.n_devices} shards")
 

@@ -42,12 +42,14 @@ class InjectionPlan:
 
 
 def _leaf_catalog(tree) -> List[Tuple[str, int, str]]:
-    """[(key, size, dtype_name)] for every array leaf."""
+    """[(key, size, dtype_name)] for every array leaf — from shapes and
+    dtypes only: no leaf leaves the device."""
     out = []
 
     def visit(path, leaf):
-        arr = np.asarray(leaf)
-        out.append((leaf_key(path), int(arr.size), str(arr.dtype)))
+        out.append((leaf_key(path),
+                    int(np.prod(jnp.shape(leaf), dtype=np.int64)),
+                    str(jnp.result_type(leaf))))
         return leaf
 
     jax.tree_util.tree_map_with_path(visit, tree)

@@ -41,9 +41,12 @@ Detection semantics are bit-identical to the non-donated
 ``check_and_arm`` protocol: slice ``s % K`` of the input state is
 verified against the generation that armed it (step ``s-1``'s output
 digest — the same buffer version), and slice ``(s+1) % K`` of the output
-is armed for step ``s+1``'s check.  The trajectory itself is bit-exact to
-the unfused step: the digest subcomputation only *reads* the state on
-either side of the user step, it never feeds back into it.
+is armed for step ``s+1``'s check.  The digest subcomputation only
+*reads* the state on either side of the user step and never feeds back
+into it, yet the trajectory is not bit-exact to a separately compiled
+step: XLA fuses the step differently inside the larger program, and on
+TPU the two round differently.  So the replay rung recomputes through
+these same executables (``replay``), never through a bare step.
 """
 
 from __future__ import annotations
@@ -293,6 +296,42 @@ class FusedStepFactory:
         ride the canary's begin/commit plumbing, so interleaving with
         ``refresh`` (post-recovery) behaves exactly like the pair path.
         """
+        new_state, aux, flag, bad, chk, ref_read = self._launch(s, state,
+                                                                args)
+        report = None
+        if flag is not None and bool(kdigest.fetch(flag)):  # ONE host sync
+            can = self.canary
+            # the commit already bumped the generation; the rows this
+            # check actually compared against live in ref_read —
+            # recovery certifies reconstructions against THEM
+            can._fault_reference = ref_read
+            # under donation the faulting input version was consumed by
+            # this very launch: the parity rung's survivors are dead, and
+            # the report says so up front (consumed=True) instead of
+            # letting the rung discover it post-hoc
+            report = FaultReport(
+                s, "checksum",
+                detail="in-step fused check",
+                resolver=lambda: can._attribute(chk, bad),
+                consumed=self.donate)
+        return new_state, aux, report
+
+    def replay(self, s: int, state, *args):
+        """Recompute step ``s`` for the replay rung: ``(new_state, aux)``
+        from the very executable ``step(s, ...)`` ran, since only the same
+        compiled program reproduces the hot path's bits (a separately
+        compiled step rounds differently on TPU).  The check's flag is
+        never read: it compares the replayed state against tables armed
+        after the fault.  The canary and parity it advances are stale
+        until the caller re-digests and rebuilds them once recovery ends
+        (``launch/train.py`` does)."""
+        new_state, aux, *_ = self._launch(s, state, args)
+        return new_state, aux
+
+    def _launch(self, s: int, state, args):
+        """Dispatch rotation ``s % K`` and commit the canary generation and
+        parity it produced: ``(new_state, aux, flag, bad, chk, ref_read)``,
+        with ``flag`` None for a degenerate rotation.  No host sync."""
         # the signature is the dispatch key — memoised on first use so
         # steady-state steps never re-flatten the args pytree
         sig = self._step_sig
@@ -306,7 +345,7 @@ class FusedStepFactory:
         kdigest.STATS.launches += 1
         if not union:                       # degenerate rotation: no digest
             new_state, aux = compiled(state, *args)
-            return new_state, aux, None
+            return new_state, aux, None, None, chk, None
         ref_read, ref_write = can.begin_update()
         pstore = can.parity_store
         if pstore is not None:
@@ -320,19 +359,4 @@ class FusedStepFactory:
                 *args)
         self.plan.put_buffer(union, buf)
         can.commit_update(new_write)
-        report = None
-        if bool(kdigest.fetch(flag)):       # the step's ONE host sync
-            # the commit above already bumped the generation; the rows
-            # this check actually compared against live in ref_read —
-            # recovery certifies reconstructions against THEM
-            can._fault_reference = ref_read
-            # under donation the faulting input version was consumed by
-            # this very launch: the parity rung's survivors are dead, and
-            # the report says so up front (consumed=True) instead of
-            # letting the rung discover it post-hoc
-            report = FaultReport(
-                s, "checksum",
-                detail="in-step fused check",
-                resolver=lambda: can._attribute(chk, bad),
-                consumed=self.donate)
-        return new_state, aux, report
+        return new_state, aux, flag, bad, chk, ref_read

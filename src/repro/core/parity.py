@@ -374,8 +374,7 @@ class ParityPlan:
         mat = self.stream_mat(leaves)
         if self.mesh is not None:
             return self._fold_rows(mat)
-        return pk.xor_fold_tiles(self._to_tiles(mat),
-                                 interpret=kdigest._interpret())
+        return pk.xor_fold_tiles(self._to_tiles(mat))
 
     def update_leaves(self, parity, old_leaves: Sequence,
                       new_leaves: Sequence, fault) -> jnp.ndarray:
@@ -390,8 +389,7 @@ class ParityPlan:
         delta = jnp.where(fault, jnp.int32(0), delta)
         if self.mesh is not None:
             return parity ^ self._fold_rows(delta)
-        return pk.xor_update_tiles(self._to_tiles(delta), parity,
-                                   interpret=kdigest._interpret())
+        return pk.xor_update_tiles(self._to_tiles(delta), parity)
 
     # -- fault path: reconstruction ---------------------------------------
 

@@ -97,6 +97,11 @@ class RecoveryRuntime:
     Parameters
     ----------
     step_fn     : jitted step(state, batch) -> (state, metrics)
+    replay_step : optional ``(step, state, batch) -> (state, metrics)``
+                  that runs step ``step`` through the loop's own hot-path
+                  executable (``FusedStepFactory.replay``).  Replay uses
+                  it when given: only the same compiled program recomputes
+                  the hot path's bits (default: ``step_fn``)
     batch_fn    : pure batch_fn(step) -> batch  (index-addressable pipeline)
     iv_registry : IVRegistry from ``core.icp.promote`` (ICP output)
     micro       : MicroCheckpointer (per-step IV log + K-step snapshots)
@@ -139,8 +144,10 @@ class RecoveryRuntime:
                  shardings=None,
                  canary: Optional[ChecksumCanary] = None,
                  triage: bool = False,
-                 elastic: Optional[Callable] = None):
+                 elastic: Optional[Callable] = None,
+                 replay_step: Optional[Callable] = None):
         self.step_fn = step_fn
+        self.replay_step = replay_step
         self.batch_fn = batch_fn
         self.ivs = iv_registry
         self.micro = micro
@@ -681,6 +688,11 @@ class RecoveryRuntime:
                      f"leaf/leaves ({moved[0]} B moved) from snapshot "
                      f"@{snap.step}")
 
+    def _step_at(self, s: int, state, batch):
+        if self.replay_step is not None:
+            return self.replay_step(s, state, batch)
+        return self.step_fn(state, batch)
+
     def _rung_replay(self, state, report: FaultReport, step: int):
         """Replay from the newest digest-verified snapshot ≤ step."""
         snap = self.micro.latest(before=step)
@@ -689,7 +701,7 @@ class RecoveryRuntime:
         rotten = self.micro.verify(snap)
         if rotten:
             raise RecoveryAbort(f"snapshot failed verification: {rotten[:3]}")
-        res = replay(self.step_fn, self.batch_fn, snap.state,
+        res = replay(self._step_at, self.batch_fn, snap.state,
                      snap.step, step,
                      like_state=None if self.donated else state,
                      shardings=self.shardings)
@@ -701,7 +713,7 @@ class RecoveryRuntime:
         if self.checkpoint is None:
             raise RecoveryAbort("no checkpoint loader configured")
         ck_state, ck_step = self.checkpoint()
-        res = replay(self.step_fn, self.batch_fn, ck_state, ck_step, step,
+        res = replay(self._step_at, self.batch_fn, ck_state, ck_step, step,
                      like_state=None if self.donated else state,
                      shardings=self.shardings)
         self._last_replayed = res.steps_replayed
@@ -724,6 +736,7 @@ class RecoveryRuntime:
         resume = self.elastic(state, report, step)
         self.pending_remesh = resume
         self.step_fn = resume.step
+        self.replay_step = None     # the old mesh's executables are gone
         self.batch_fn = resume.bfn
         self.shardings = resume.shardings
         if resume.canary is not None:

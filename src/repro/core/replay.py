@@ -8,7 +8,11 @@ that the whole step function is pure:
 
 so given any *verified* snapshot at step t0 <= t, the exact state at t is
 recomputable by replaying (t - t0) deterministic steps — no I/O, no lost
-work beyond the replayed window, bit-exact on the same topology.
+work beyond the replayed window, bit-exact when each step runs the same
+compiled program as the run being recovered.  XLA promises identical bits
+only for one executable: on TPU a step compiled alone and the same step
+compiled inside the in-step fused canary program round differently, so
+the replay must be handed the hot path's own executables.
 
 The snapshot plays the paper's "terminal values" role: the micro-checkpointer
 guarantees (by digest verification — our liveness analysis) that the replay
@@ -57,22 +61,22 @@ def device_put_like(host_state, like_state=None, shardings=None):
     return jax.tree_util.tree_map(put, host_state, like_state)
 
 
-def replay(step_fn: Callable, batch_fn: Callable, snapshot_state,
+def replay(step_at: Callable, batch_fn: Callable, snapshot_state,
            from_step: int, to_step: int, *, like_state=None,
            shardings=None, on_step: Optional[Callable] = None
            ) -> ReplayResult:
-    """Replay ``step_fn`` from ``from_step`` (exclusive state snapshot taken
-    *before* executing step ``from_step``) up to (but not including)
-    ``to_step``.
+    """Replay steps ``from_step`` (the snapshot was taken *before*
+    executing it) up to (but not including) ``to_step``.
 
-    step_fn(state, batch) -> (state, metrics); batch_fn(step) -> batch.
+    step_at(step, state, batch) -> (state, metrics) runs step ``step`` with
+    the program the recovered run used for it; batch_fn(step) -> batch.
     ``shardings`` places the snapshot on a mesh when no ``like_state``
     reference survives (donated loops).
     """
     assert to_step >= from_step, (from_step, to_step)
     state = device_put_like(snapshot_state, like_state, shardings)
     for s in range(from_step, to_step):
-        state, _ = step_fn(state, batch_fn(s))
+        state, _ = step_at(s, state, batch_fn(s))
         if on_step is not None:
             on_step(s, state)
     return ReplayResult(state=state, steps_replayed=to_step - from_step,

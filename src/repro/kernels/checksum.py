@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: blocked Fletcher-style checksum.
+"""Pallas TPU kernel: row-granular Fletcher-style checksum.
 
 This is the paper's "zero-overhead detection" made real on TPU: the canary
 detector must stream the full train state at HBM bandwidth with no MXU use
@@ -6,22 +6,14 @@ and negligible VMEM residency, so it can overlap with step compute.
 
 Layout: the flat int32 view is tiled (TILE_ROWS, LANES) = (256, 128) — one
 VMEM-resident tile is 128 KiB, well under the ~16 MiB/core budget, and the
-lane dim matches the VPU's native 128-lane registers.  Each grid step
-produces a (2,)-digest for its tile; tile digests are combined *exactly*
-into per-block digests by the ops wrapper (the weighted term needs a global
-offset correction: Σ(i+g)·x = Σi·x + g·Σx, all mod 2^32).
+lane dim matches the VPU's native 128-lane registers.
 
-Two entry points:
-
-* ``checksum_tiles`` — per-tile digests with *local* weights; the caller
-  applies the offset correction (legacy single-array path).
-* ``row_checksums``  — the fused-digest variant (DESIGN.md §4.2): one
-  launch digests every 128-lane ROW of a whole train state packed into a
-  single buffer.  Row granularity lets the DigestPlan pack leaves
-  back-to-back at 512 B alignment (tile alignment would inflate a state
-  with many small leaves by up to 256×), and per-leaf digests fall out of
-  a plain segment-sum over the row digests — no per-leaf launches, no
-  per-leaf host syncs.
+``row_checksums`` digests every 128-lane ROW of its input in one launch.
+The fused digest (DESIGN.md §4.2) packs a whole train state into a single
+buffer at row (512 B) alignment — tile alignment would inflate a state
+with many small leaves by up to 256× — and per-leaf digests fall out of a
+plain segment-sum over the row digests: no per-leaf launches, no per-leaf
+host syncs.  ``ops.checksum`` is the same combine over one array.
 """
 
 from __future__ import annotations
@@ -30,28 +22,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import interpret_mode
+
 LANES = 128
 TILE_ROWS = 256
 TILE = TILE_ROWS * LANES  # 32768 int32 = 128 KiB per VMEM tile
-
-
-def _tile_sums(x):
-    """(s1, s2_local) of one (TILE_ROWS, LANES) int32 tile."""
-    rows, lanes = x.shape
-    # local position weights 1..TILE (row-major within the tile)
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
-    idx = row * lanes + lane + 1
-    s1 = jnp.sum(x, dtype=jnp.int32)
-    s2 = jnp.sum(x * idx, dtype=jnp.int32)
-    return s1, s2
-
-
-def _checksum_kernel(x_ref, out_ref):
-    """x_ref: (1, TILE_ROWS, LANES) int32 tile; out_ref: (1, 2) int32."""
-    s1, s2 = _tile_sums(x_ref[0, :, :])
-    out_ref[0, 0] = s1
-    out_ref[0, 1] = s2
 
 
 def _row_checksum_kernel(x_ref, out_ref):
@@ -82,58 +57,7 @@ def _row_checksum_batch_kernel(x_ref, out_ref):
     out_ref[..., 1] = jnp.sum(x * lane[None, :, :], axis=2, dtype=jnp.int32)
 
 
-def pack_rows(buf: jnp.ndarray, flats, starts, *, interpret: bool = True):
-    """In-place scatter of leaf bit-streams into the persistent packing
-    buffer (DESIGN.md §4.2 buffer reuse).
-
-    buf    : flat int32 packing buffer — ALIASED into the output
-             (``input_output_aliases={0: 0}``), so when the caller's jit
-             donates it the pack is a true in-place write: zero new device
-             allocations per digest in steady state.
-    flats  : flat int32 views of the leaves (``ref.to_i32`` output).
-    starts : static element offset of each flat within ``buf`` (the plan's
-             row-aligned layout).
-
-    Only the leaf ranges are written; the inter-leaf fill and the tail pad
-    are zero-initialised once at buffer creation and never touched again
-    (leaf sizes are plan constants, so the zero regions are invariant).
-    Compiled-TPU note: the un-gridded whole-buffer form below is the
-    interpret/CPU path; a compiled TPU pack would keep ``buf`` in HBM
-    (``memory_space=pltpu.HBM``) and DMA per leaf — see DESIGN.md
-    "Follow-on work".
-    """
-    starts = tuple(int(s) for s in starts)
-
-    def kernel(*refs):
-        # refs = (buf_ref, *leaf_refs, out_ref); buf_ref is aliased to
-        # out_ref, so untouched regions keep their (zero) contents.
-        out_ref = refs[-1]
-        for leaf_ref, start in zip(refs[1:-1], starts):
-            out_ref[pl.ds(start, leaf_ref.shape[0])] = leaf_ref[...]
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(buf, *flats)
-
-
-def checksum_tiles(x_i32_tiles: jnp.ndarray, *, interpret: bool = True):
-    """x_i32_tiles: (nt, TILE_ROWS, LANES) int32 -> (nt, 2) int32 digests."""
-    nt = x_i32_tiles.shape[0]
-    return pl.pallas_call(
-        _checksum_kernel,
-        grid=(nt,),
-        in_specs=[pl.BlockSpec((1, TILE_ROWS, LANES),
-                               lambda i: (i, 0, 0))],
-        out_specs=pl.BlockSpec((1, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nt, 2), jnp.int32),
-        interpret=interpret,
-    )(x_i32_tiles)
-
-
-def row_checksums(x_i32_tiles: jnp.ndarray, *, interpret: bool = True):
+def row_checksums(x_i32_tiles: jnp.ndarray, *, interpret=None):
     """Single-launch whole-state digest pass at ROW granularity.
 
     x_i32_tiles : (nt, TILE_ROWS, LANES) int32 — every row of every leaf,
@@ -151,12 +75,20 @@ def row_checksums(x_i32_tiles: jnp.ndarray, *, interpret: bool = True):
     would make the tiled grid quadratic in state size.
     """
     nt = x_i32_tiles.shape[0]
-    if interpret:
+    if interpret_mode(interpret):
         return pl.pallas_call(
             _row_checksum_batch_kernel,
             out_shape=jax.ShapeDtypeStruct((nt, TILE_ROWS, 2), jnp.int32),
             interpret=True,
         )(x_i32_tiles)
+    return _gridded_row_checksums(x_i32_tiles, interpret=False)
+
+
+def _gridded_row_checksums(x_i32_tiles: jnp.ndarray, *, interpret: bool):
+    """The compiled form of ``row_checksums``: a grid over tiles, one
+    (TILE_ROWS, LANES) block per step.  Tests run it interpreted at small
+    ``nt`` to check its semantics against the batch kernel."""
+    nt = x_i32_tiles.shape[0]
     return pl.pallas_call(
         _row_checksum_kernel,
         grid=(nt,),

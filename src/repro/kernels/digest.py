@@ -22,11 +22,13 @@ no-fault hot path.  This module replaces that with (DESIGN.md §4.2):
   scalar "any mismatch?" flag per check.  Leaf attribution via the
   leaf-index→path map happens only on the slow (fault) path.
 * **persistent packing buffer** — each (plan, leaf-subset) owns ONE
-  packing buffer for the lifetime of the plan.  The pack step is a Pallas
-  kernel with ``input_output_aliases`` (``checksum.pack_rows``) and every
-  jitted digest donates the buffer back into itself, so a steady-state
-  digest makes zero new device allocations: the same HBM range is
-  rewritten in place every step (donation-safe hot path; DESIGN.md §4.2).
+  packing buffer for the lifetime of the plan.  The pack step writes each
+  leaf's range with ``lax.dynamic_update_slice`` at its static offset and
+  every jitted digest donates the buffer back into itself, so XLA updates
+  it in place and a steady-state digest makes zero new device
+  allocations: the same HBM range is rewritten every step (donation-safe
+  hot path; DESIGN.md §4.2).  The writes stream HBM to HBM at any state
+  size; a whole-buffer Pallas pack kernel would need the buffer in VMEM.
 * **host digest path** — ``host_checksum``/``host_tree_checksums`` compute
   the same Fletcher digests in numpy uint32 wraparound arithmetic,
   bit-identical to the kernel, so micro-snapshot host DMA copies are
@@ -68,8 +70,8 @@ bit-identical to the single-device ``host_checksum`` oracle applied to
 each shard's bytes (``host_shard_checksums``).
 
 Instrumentation: ``STATS`` counts launches (one per digest invocation —
-each digest is one in-place pack + one ``row_checksums`` pallas_call,
-counted as a single fused launch; the in-step fused mode counts its one
+each digest is one jitted program: the in-place pack + one
+``row_checksums`` pallas_call, counted as a single fused launch; the in-step fused mode counts its one
 combined step+digest dispatch), host syncs (every device→host fetch in
 this module and in the canary goes through ``fetch``), and traces
 (incremented inside traced bodies, so a plan-cache hit provably does not
@@ -84,7 +86,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.ops import segment_sum
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -120,10 +121,6 @@ def fetch(x) -> np.ndarray:
     """The ONLY device→host crossing in the digest subsystem — counted."""
     STATS.syncs += 1
     return np.asarray(x)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -247,12 +244,12 @@ class DigestPlan:
             STATS.traces += 1          # trace-time only: counts cache misses
             # in-place row-aligned packing into the persistent buffer: only
             # the leaf ranges are written (fill/tail rows are permanently
-            # zero), and input_output_aliases + caller donation make the
-            # write allocation-free in steady state
-            flats = [_ref.to_i32(leaf) for leaf in leaves]
-            buf = _ck.pack_rows(buf, flats, starts, interpret=_interpret())
-            d = _ck.row_checksums(buf.reshape(nt, TILE_ROWS, LANES),
-                                  interpret=_interpret()) \
+            # zero), and caller donation makes the writes allocation-free
+            # in steady state
+            for leaf, start in zip(leaves, starts):
+                buf = jax.lax.dynamic_update_slice(
+                    buf, _ref.to_i32(leaf), (start,))
+            d = _ck.row_checksums(buf.reshape(nt, TILE_ROWS, LANES)) \
                 .reshape(padded_rows, 2)
             seg = jnp.asarray(seg_ids)
             s1 = segment_sum(d[:, 0], seg, num_segments=n_seg)
@@ -434,7 +431,7 @@ class ShardedDigestPlan(DigestPlan):
     The inherited layout (``specs``/``n_rows``/row maps) is computed over
     the LOCAL shard sizes — every device owns an identical private layout
     because GSPMD shard shapes are uniform — so the whole single-device
-    digest core (in-place pack kernel + one ``row_checksums`` pallas_call
+    digest core (in-place pack + one ``row_checksums`` pallas_call
     + exact segment-sum combine) runs unchanged INSIDE ``shard_map``, once
     per device, in the same single logical launch.  Global artifacts grow
     a leading shard dim, sharded over all mesh axes flattened:
@@ -496,11 +493,11 @@ class ShardedDigestPlan(DigestPlan):
             b, t = local(buf[0], blocks)
             return b[None], t[None]
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(self.buf_spec,) + tuple(self.pspecs[i] for i in idx),
             out_specs=(self.buf_spec, self.table_spec),
-            check_rep=False)
+            check_vma=False)
 
         def digest(buf, leaves):
             return fn(buf, *leaves)
@@ -716,13 +713,13 @@ def _sharded_check_arm_subcomputation(plan: ShardedDigestPlan,
             if arm else ref_write
         return b[None], flag, bad[None], new_write
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_fn, mesh=plan.mesh,
         in_specs=(plan.buf_spec, plan.table_spec, plan.table_spec)
         + tuple(plan.pspecs[i] for i in union),
         out_specs=(plan.buf_spec, P(), P(plan.axis_names, None),
                    plan.table_spec),
-        check_rep=False)
+        check_vma=False)
 
     def fn(buf, leaves, ref_read, ref_write):
         return smapped(buf, ref_read, ref_write, *leaves)

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import interpret_mode
+
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 _NEG_INF = -2.0**30
@@ -106,7 +108,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          softcap: float = 0.0, block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool = True):
+                         interpret=None):
     """q (BH, Sq, D), k/v (BKV, Sk, D) pre-padded to block/lane multiples;
     BH = B*H and BKV = B*KV flattened.  Returns o (BH, Sq, D)."""
     BH, Sq, D = q.shape
@@ -135,5 +137,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q,), jnp.float32),      # l (running denom)
             pltpu.VMEM((block_q, D), jnp.float32),    # acc (unnormalised o)
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

@@ -1,9 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles: arbitrary shapes/dtypes (bit-cast + pad to tile multiples), exact
-digest recombination across tiles, interpret-mode selection (Pallas kernels
-execute in interpret mode on CPU; compiled mode on TPU), and pytree-level
-orchestration (leaf digests for whole train states).
+digest recombination across rows, and pytree-level orchestration (leaf
+digests for whole train states).  Every kernel decides interpret vs
+compiled mode itself (``kernels.backend.interpret_mode``).
 """
 
 from __future__ import annotations
@@ -24,10 +24,6 @@ from repro.kernels.digest import leaf_key, plan_for  # noqa: F401 (re-export)
 TILE = _ck.TILE  # int32 elements per kernel tile
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _tiles(x) -> Tuple[jnp.ndarray, int]:
     """Flat int32 view padded and reshaped to (nt, TILE_ROWS, LANES)."""
     flat = _ref.to_i32(x)
@@ -41,28 +37,16 @@ def _tiles(x) -> Tuple[jnp.ndarray, int]:
 def checksum(x) -> jnp.ndarray:
     """Two-term Fletcher digest int32[2] of the raw bits of ``x``.
 
-    Tile digests (s1_t, s2_t) combine exactly:
-        s1 = Σ_t s1_t
-        s2 = Σ_t (s2_t + offset_t · s1_t)      (mod 2^32)
+    Row digests (s1_r, s2_r) of ``row_checksums`` combine exactly:
+        s1 = Σ_r s1_r
+        s2 = Σ_r (s2_r + offset_r · s1_r)      (mod 2^32)
     """
     tiles, _ = _tiles(x)
-    d = _ck.checksum_tiles(tiles, interpret=_interpret())  # (nt, 2)
-    nt = d.shape[0]
-    offsets = jnp.arange(nt, dtype=jnp.int32) * jnp.int32(TILE)
+    d = _ck.row_checksums(tiles).reshape(-1, 2)
+    offsets = jnp.arange(d.shape[0], dtype=jnp.int32) * jnp.int32(_ck.LANES)
     s1 = jnp.sum(d[:, 0], dtype=jnp.int32)
     s2 = jnp.sum(d[:, 1] + offsets * d[:, 0], dtype=jnp.int32)
     return jnp.stack([s1, s2])
-
-
-@jax.jit
-def blocked_checksum(x) -> jnp.ndarray:
-    """Per-tile digests int32[nt, 2].  Localisation granularity is the
-    kernel tile: TILE = TILE_ROWS·LANES = 32768 int32 elements = 128 KiB
-    (coarser than the pure-jnp oracle's ``ref.CHECKSUM_BLOCK`` default —
-    the oracle block size is a reference-semantics knob, not the kernel's
-    tiling)."""
-    tiles, _ = _tiles(x)
-    return _ck.checksum_tiles(tiles, interpret=_interpret())
 
 
 @jax.jit
@@ -71,7 +55,7 @@ def vote3(a, b, c):
     ta, n = _tiles(a)
     tb, _ = _tiles(b)
     tc, _ = _tiles(c)
-    out = _vk.vote3_tiles(ta, tb, tc, interpret=_interpret())
+    out = _vk.vote3_tiles(ta, tb, tc)
     return _ref.from_i32(out.reshape(-1)[:n], a)
 
 
@@ -84,7 +68,7 @@ def xor_fold(arrays: Sequence[jnp.ndarray]):
         t, n = _tiles(a)
         ts.append(t)
     stacked = jnp.stack(ts)  # (R, nt, rows, lanes)
-    out = _pk.xor_fold_tiles(stacked, interpret=_interpret())
+    out = _pk.xor_fold_tiles(stacked)
     return _ref.from_i32(out.reshape(-1)[:n], arrays[0])
 
 
@@ -133,7 +117,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     o = _fa.flash_attention_bhsd(
         qf * np.sqrt((D + pad_d) / D).astype(qf.dtype),
         kf, vf, causal=causal, window=window, softcap=softcap,
-        block_q=bq, block_k=bk, interpret=_interpret())
+        block_q=bq, block_k=bk)
     o = o.reshape(B, H, Sq + pad_q, D + pad_d).transpose(0, 2, 1, 3)
     return o[:, :Sq, :, :D]
 

@@ -44,9 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.backend import interpret_mode
 
 
 def gather_blocks(pool_leaf, block_tables, *, interpret=None):
@@ -62,20 +60,18 @@ def gather_blocks(pool_leaf, block_tables, *, interpret=None):
     ordered — ``out[s].reshape(max_blocks * block_size, *feat)`` is slot
     ``s``'s linear cache view.
     """
-    if interpret is None:
-        interpret = _interpret()
     nb, bs = pool_leaf.shape[:2]
     feat = pool_leaf.shape[2:]
     F = int(np.prod(feat, dtype=np.int64)) if feat else 1
     S, mb = block_tables.shape
     pool3 = pool_leaf.reshape(nb, bs, F)
 
-    def kernel(bt_ref, pool_ref, out_ref):
+    def _gather_block_kernel(bt_ref, pool_ref, out_ref):
         del bt_ref  # consumed by the index_map, not the body
         out_ref[0, 0] = pool_ref[0]
 
     out = pl.pallas_call(
-        kernel,
+        _gather_block_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(S, mb),
@@ -86,7 +82,7 @@ def gather_blocks(pool_leaf, block_tables, *, interpret=None):
                                    lambda s, j, bt: (s, j, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((S, mb, bs, F), pool_leaf.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_tables, pool3)
     return out.reshape((S, mb, bs) + feat)
 

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import interpret_mode
+
 LANES = 128
 TILE_ROWS = 256
 
@@ -27,7 +29,7 @@ def _xor_fold_kernel(x_ref, out_ref):
     out_ref[0] = acc
 
 
-def xor_fold_tiles(x, *, interpret: bool = True):
+def xor_fold_tiles(x, *, interpret=None):
     """x: (R, nt, TILE_ROWS, LANES) int32 -> parity (nt, TILE_ROWS, LANES)."""
     R, nt = x.shape[0], x.shape[1]
     return pl.pallas_call(
@@ -37,7 +39,7 @@ def xor_fold_tiles(x, *, interpret: bool = True):
                                lambda i: (0, i, 0, 0))],
         out_specs=pl.BlockSpec((1, TILE_ROWS, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nt, TILE_ROWS, LANES), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x)
 
 
@@ -49,7 +51,7 @@ def _xor_update_kernel(x_ref, p_ref, out_ref):
     out_ref[0] = acc
 
 
-def xor_update_tiles(x, parity, *, interpret: bool = True):
+def xor_update_tiles(x, parity, *, interpret=None):
     """Incremental parity update: ``parity ^ XOR_d x[d]``.
 
     ``x``: (D, nt, TILE_ROWS, LANES) int32 per-shard delta tiles
@@ -70,5 +72,5 @@ def xor_update_tiles(x, parity, *, interpret: bool = True):
         out_specs=pl.BlockSpec((1, TILE_ROWS, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nt, TILE_ROWS, LANES), jnp.int32),
         input_output_aliases={1: 0},
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, parity)

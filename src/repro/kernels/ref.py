@@ -11,8 +11,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-CHECKSUM_BLOCK = 4096  # elements per digest block (int32 lanes)
-
 
 def to_i32(x) -> jnp.ndarray:
     """Bit-cast any array to a flat int32 vector (zero-padded to 4-byte
@@ -50,21 +48,6 @@ def checksum_ref(x) -> jnp.ndarray:
     s1 = jnp.sum(flat, dtype=jnp.int32)
     s2 = jnp.sum(flat * idx, dtype=jnp.int32)
     return jnp.stack([s1, s2])
-
-
-def blocked_checksum_ref(x, block: int = CHECKSUM_BLOCK) -> jnp.ndarray:
-    """Per-block digests int32[nb, 2] — the localisation variant: a corrupt
-    element identifies its block, so repair touches one block, not the whole
-    leaf."""
-    flat = to_i32(x)
-    n = flat.shape[0]
-    nb = -(-n // block)
-    flat = jnp.pad(flat, (0, nb * block - n))
-    blocks = flat.reshape(nb, block)
-    idx = (jnp.arange(block, dtype=jnp.int32) + 1)[None, :]
-    s1 = jnp.sum(blocks, axis=1, dtype=jnp.int32)
-    s2 = jnp.sum(blocks * idx, axis=1, dtype=jnp.int32)
-    return jnp.stack([s1, s2], axis=1)
 
 
 def vote3_ref(a, b, c):

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import interpret_mode
+
 LANES = 128
 TILE_ROWS = 256
 
@@ -24,7 +26,7 @@ def _vote_kernel(a_ref, b_ref, c_ref, out_ref):
     out_ref[0] = (a & b) | (a & c) | (b & c)
 
 
-def vote3_tiles(a, b, c, *, interpret: bool = True):
+def vote3_tiles(a, b, c, *, interpret=None):
     """a/b/c: (nt, TILE_ROWS, LANES) int32 -> majority (nt, TILE_ROWS, LANES)."""
     nt = a.shape[0]
     spec = pl.BlockSpec((1, TILE_ROWS, LANES), lambda i: (i, 0, 0))
@@ -34,5 +36,5 @@ def vote3_tiles(a, b, c, *, interpret: bool = True):
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(a.shape, jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a, b, c)

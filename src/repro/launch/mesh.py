@@ -11,6 +11,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,12 +19,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     add a leading "pod" axis (DCN) => 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Arbitrary mesh (used by §Perf sharding experiments)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """THE mesh constructor of this repo (tests and benchmarks included).
+
+    Every axis is ``AxisType.Auto``: the sharding code places arrays with
+    ``NamedSharding``/``with_sharding_constraint`` and lets GSPMD propagate
+    the rest.  ``jax.make_mesh`` defaults to Explicit axes, under which
+    jitted code outside a ``jax.set_mesh`` context refuses those
+    placements."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def parse_mesh(spec: Optional[str]):

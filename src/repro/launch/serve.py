@@ -34,6 +34,7 @@ import random
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import Request, ServingEngine
 from repro.serving.engine import ServingReport   # noqa: F401 (re-export)
 
@@ -73,6 +74,9 @@ def serve(cfg, *, n_requests: int, prompt_len: int, gen_tokens: int,
     ``fused_detect`` is accepted for CLI compatibility: the engine step is
     always in-step fused.
 
+    The summary carries ``outputs`` (request id -> generated tokens) and
+    ``injured`` (ids of the requests a fault touched).
+
     ``parity=True`` adds at-rest protection for the STATIC params: one
     XOR parity build at load time (1/D memory), then an end-of-run
     ``scrub_params`` sweep that detects and repairs silent weight rot in
@@ -106,6 +110,11 @@ def serve(cfg, *, n_requests: int, prompt_len: int, gen_tokens: int,
     eng.warm()
     rep = eng.run(reqs, inject_every=inject_every, inject_rng=rng)
     out = rep.summary()
+    # every request's generated tokens, and which requests a fault touched:
+    # what a caller compares against a fault-free run on the same seed
+    out["outputs"] = {rid: r["tokens"]
+                      for rid, r in sorted(rep.per_request.items())}
+    out["injured"] = sorted(rep.injured_rids)
     if parity:
         if inject_every:
             # at-rest weight-rot adversary: flip one param bit after the
@@ -158,6 +167,7 @@ def main():
                          "no checkpoint reload")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

@@ -47,6 +47,7 @@ from repro.core import (
 )
 from repro.core.detect import LOSS_WINDOW
 from repro.data.pipeline import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_context
 from repro.launch.specs import bind_state
 from repro.train.loop import (
@@ -70,6 +71,7 @@ class LoopReport:
         out = {
             "steps": self.steps,
             "final_loss": self.losses[-1] if self.losses else None,
+            "losses": list(self.losses),
             "faults_injected": self.faults_injected,
             "faults_detected": self.faults_detected,
             "faults_recovered": self.faults_recovered,
@@ -102,8 +104,10 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
           fused_warm: str = "eager", mesh: Optional[str] = None,
           parity: bool = False, triage: bool = False,
           elastic: bool = False, kill_row_at: Optional[int] = None,
-          verbose: bool = True) -> Dict:
-    """Run the recovery-wrapped loop; returns the loop report dict.
+          verbose: bool = True, return_state: bool = False):
+    """Run the recovery-wrapped loop; returns the loop report dict, or
+    ``(report, final_state)`` with ``return_state=True`` (for callers that
+    verify the trained state itself, e.g. against a host digest oracle).
 
     ``donate=True`` is the production compilation setting: the step is
     jitted with ``donate_argnums=(0,)`` so XLA updates the train state in
@@ -221,19 +225,14 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
                                  pstore=pstore, donate=donate)
     if kill_row_at is not None and emgr is None:
         raise ValueError("kill_row_at requires elastic=True")
-    runtime = RecoveryRuntime(
-        step_fn=step_fn,
-        batch_fn=bfn, iv_registry=promote(cfg, global_batch), micro=micro,
-        parity=pstore, checkpoint=ckpt.loader(state) if ckpt else None,
-        donated=donate, shardings=shardings, canary=canary, triage=triage,
-        elastic=elastic_hook)
     fused = None
     if fused_detect:
         if canary is None:
             raise ValueError("fused_detect requires detectors=True "
                              "(the canary IS the in-step detector)")
         # the factory jits the RAW step together with the canary check/arm;
-        # the separately jitted step_fn above still serves replay/recovery
+        # replay runs through the same executables (``fused.replay``), so
+        # a replayed step reproduces the hot path's bits
         fused = canary.fuse_into_step(raw_step, donate=donate,
                                       warm=fused_warm)
         if fused_warm == "eager":
@@ -241,6 +240,13 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
             # first step's wall time doesn't absorb them ('lazy' keeps
             # the documented pay-per-rotation behaviour)
             fused.warm(state, bfn(0))
+    runtime = RecoveryRuntime(
+        step_fn=step_fn,
+        batch_fn=bfn, iv_registry=promote(cfg, global_batch), micro=micro,
+        parity=pstore, checkpoint=ckpt.loader(state) if ckpt else None,
+        donated=donate, shardings=shardings, canary=canary, triage=triage,
+        elastic=elastic_hook,
+        replay_step=fused.replay if fused is not None else None)
 
     rng = random.Random(seed + 7)
     rep = LoopReport()
@@ -369,6 +375,7 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
                                                   warm=fused_warm)
                     if fused_warm == "eager":
                         fused.warm(state, bfn(s))
+                    runtime.replay_step = fused.replay
                 rep.elastic_events.append(resume.event.to_dict())
             else:
                 if canary is not None:
@@ -401,7 +408,7 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
     if ctx is not None:
         out["mesh"] = {"shape": dict(ctx.mesh.shape),
                        "devices": ctx.n_devices}
-    return out
+    return (out, state) if return_state else out
 
 
 def main():
@@ -467,6 +474,7 @@ def main():
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

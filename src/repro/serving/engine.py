@@ -91,7 +91,6 @@ from repro.core.faults import flip_bit
 from repro.core.fused_step import _args_signature, _sds
 from repro.core.recover import plan_serving_recovery
 from repro.kernels import digest as kdigest
-from repro.kernels import paged_kv as pkv
 from repro.kernels.ops import leaf_key
 from repro.models.registry import get_model
 from repro.serving import paged as pgd
@@ -553,7 +552,6 @@ class ServingEngine:
         model, m, S, repl = self.model, self.m, self.S, self._repl
         plan, canary = self.plan, self.canary
         NB, bs = self.n_blocks, self.block_size
-        interp = pkv._interpret()
 
         def vdecode(params, gcache, tok):
             def one(c, t):
@@ -568,7 +566,7 @@ class ServingEngine:
                 lambda x: jax.lax.with_sharding_constraint(x, repl), tree)
 
         def step_core(params, pool, bt, pos, amask, tok, fmask, ftok):
-            gcache = pgd.gathered_cache(pool, bt, pos, interpret=interp)
+            gcache = pgd.gathered_cache(pool, bt, pos)
             logits, ngc = vdecode(params, gcache, tok)
             npool = pgd.scatter_token(pool, ngc["groups"], bt, pos, amask,
                                       bs)

@@ -151,7 +151,7 @@ def make_block_pool(per_slot, n_blocks: int, block_size: int):
     return {"groups": tree_map(pool_leaf, per_slot["groups"])}
 
 
-def gathered_cache(pool, bt, pos, *, interpret=None):
+def gathered_cache(pool, bt, pos):
     """Materialise the dense slot-major cache view the vmapped decode step
     expects, via the Pallas block gather (one DMA program per
     (slot, logical block)).
@@ -163,7 +163,7 @@ def gathered_cache(pool, bt, pos, *, interpret=None):
     (prefill zero-padding), so zeroing here is what makes the gathered
     view bit-identical to the dense one."""
     def g(leaf):
-        out = gather_blocks(leaf, bt, interpret=interpret)
+        out = gather_blocks(leaf, bt)
         S, mb, bs, count = out.shape[:4]
         feat = out.shape[4:]
         out = out.reshape((S, mb * bs, count) + feat)

@@ -10,16 +10,6 @@ from repro.configs import get_config
 from repro.data.pipeline import TokenPipeline
 from repro.train.loop import make_train_state, make_train_step
 
-#: shared version guard: the multi-device subprocess programs
-#: (test_hlo_cost / test_moe / test_pipeline) build their meshes with
-#: ``jax.sharding.AxisType`` (newer jax); on older jax the subprocess
-#: would die with AttributeError — skip with a reasoned marker instead
-#: of red noise, importorskip-style.
-requires_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType not available in this jax version")
-
-
 @pytest.fixture(scope="session")
 def tiny_cfg():
     """A very small config for fast loop-level tests."""

@@ -46,7 +46,8 @@ mesh8 = pytest.mark.skipif(
 
 def _ctx():
     from repro.distributed.context import DistContext
-    return DistContext.for_mesh(jax.make_mesh((4, 2), ("data", "model")))
+    from repro.launch.mesh import make_mesh
+    return DistContext.for_mesh(make_mesh((4, 2), ("data", "model")))
 
 
 def _toy_tree(ctx):
@@ -331,6 +332,7 @@ _DRILL = textwrap.dedent("""
     from repro.distributed.context import DistContext
     from repro.kernels import digest as kdigest
     from repro.launch.elastic import ElasticManager, stolen_batch
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import bind_state
     from repro.train.loop import make_train_state, make_train_step
 
@@ -339,7 +341,7 @@ _DRILL = textwrap.dedent("""
     cfg = dataclasses.replace(
         cfg, sharding=dataclasses.replace(cfg.sharding, fsdp=True))
     B, S, KILL, STEPS = 12, 16, 3, 7
-    ctx = DistContext.for_mesh(jax.make_mesh((4, 2), ("data", "model")))
+    ctx = DistContext.for_mesh(make_mesh((4, 2), ("data", "model")))
     pipe = TokenPipeline(cfg.model.vocab_size, S, B, seed=0)
     state = make_train_state(cfg, jax.random.PRNGKey(0), global_batch=B)
     raw_bfn = lambda s: pipe.batch_at(s)

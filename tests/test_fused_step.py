@@ -276,6 +276,27 @@ def test_fused_donated_flip_detected_and_recovery_refresh_resumes():
     assert rep is not None and rep.resolve() == ["tok"]
 
 
+def test_replay_recomputes_the_step_without_a_sync_or_report():
+    """``replay`` (the replay rung's step) runs the same executable as
+    ``step``: same output bits, no flag sync, and a fault reference left
+    by the detected fault stays put for the rungs that certify against
+    it."""
+    state = _tree()
+    can = ChecksumCanary(state, n_slices=1)
+    fac = can.fuse_into_step(_raw_step, donate=False)
+    want, _, rep = fac.step(0, state, BATCH)
+    assert rep is None
+    bad = dict(want, opt={"m": flip_bit(want["opt"]["m"], 11, 4)})
+    _, _, rep = fac.step(1, bad, BATCH)
+    assert rep is not None
+    fault_ref = can._fault_reference
+    dg.STATS.reset()
+    got, aux = fac.replay(0, state, BATCH)
+    assert dg.STATS.syncs == 0 and dg.STATS.launches == 1
+    assert can._fault_reference is fault_ref
+    assert _same_tree(got, want) and float(aux["loss"]) == 8.0
+
+
 def test_degenerate_rotations_more_slices_than_leaves():
     """K > n_leaves: empty rotations run the plain step (no digest, no
     generation bump) and the populated rotations still guard their

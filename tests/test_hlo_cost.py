@@ -5,6 +5,7 @@ first jax init; the test session must keep seeing 1 CPU device).
 """
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -12,8 +13,9 @@ import textwrap
 import jax
 import jax.numpy as jnp
 
-from conftest import requires_axis_type
 from repro.launch import hlo_cost as HC
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_single_device_matmul_flops():
@@ -50,9 +52,9 @@ SUBPROCESS_PROG = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch import hlo_cost as HC
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    mesh = make_mesh((2, 4), ("data", "model"))
     M, K, N = 512, 256, 1024
     a = jax.ShapeDtypeStruct((M, K), jnp.float32)
     b = jax.ShapeDtypeStruct((K, N), jnp.float32)
@@ -79,10 +81,9 @@ SUBPROCESS_PROG = textwrap.dedent("""
 """)
 
 
-@requires_axis_type
 def test_spmd_per_device_flops_and_collectives():
     out = subprocess.run([sys.executable, "-c", SUBPROCESS_PROG],
-                         capture_output=True, text=True, cwd="/root/repo",
+                         capture_output=True, text=True, cwd=REPO,
                          timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     data = json.loads(out.stdout.strip().splitlines()[-1])

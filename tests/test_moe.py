@@ -2,6 +2,7 @@
 when capacity is ample; EP path equivalence on a multi-device subprocess."""
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -10,9 +11,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from conftest import requires_axis_type
 from repro.configs.base import ModelConfig
 from repro.models import moe as M
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _cfg(E=4, k=2, d=16, ff=32, cap=1.25):
@@ -78,6 +80,7 @@ EP_PROG = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs.base import ModelConfig
     from repro.distributed.context import DistContext
+    from repro.launch.mesh import make_mesh
     from repro.models import moe as M
 
     out = {}
@@ -87,8 +90,7 @@ EP_PROG = textwrap.dedent("""
                           top_k=2, moe_d_ff=32, moe_impl=impl,
                           moe_capacity=8.0,
                           param_dtype="float32", compute_dtype="float32")
-        mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = DistContext.for_mesh(mesh, fsdp=True)
         key = jax.random.PRNGKey(0)
         p = M.moe_init(key, cfg, jnp.float32)
@@ -104,12 +106,11 @@ EP_PROG = textwrap.dedent("""
 """)
 
 
-@requires_axis_type
 def test_ep_paths_match_local():
     """Both EP schedules (mask+psum baseline and token-routed a2a, §Perf B4)
     must agree with the single-device oracle."""
     out = subprocess.run([sys.executable, "-c", EP_PROG],
-                         capture_output=True, text=True, cwd="/root/repo",
+                         capture_output=True, text=True, cwd=REPO,
                          timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     data = json.loads(out.stdout.strip().splitlines()[-1])

@@ -173,9 +173,10 @@ def test_tp_sharded_slice_map_regression():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.distributed.context import DistContext
+    from repro.launch.mesh import make_mesh
 
     n = len(jax.devices())
-    mesh = jax.make_mesh((n // 2, 2), ("data", "model"))
+    mesh = make_mesh((n // 2, 2), ("data", "model"))
     ctx = DistContext.for_mesh(mesh)
     leaf = jnp.arange(16 * 256, dtype=jnp.float32).reshape(16, 256)
     sh = NamedSharding(mesh, P(None, "model"))       # TP, DP-replicated

@@ -3,11 +3,13 @@ composition of stages, for any (stages, microbatches) combination.
 Runs on a subprocess mesh (the test session keeps 1 device)."""
 
 import json
+import os
 import subprocess
 import sys
 import textwrap
 
-from conftest import requires_axis_type
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 PIPE_PROG = textwrap.dedent("""
     import os, json
@@ -16,10 +18,10 @@ PIPE_PROG = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     from repro.distributed.pipeline import pipeline_apply
+    from repro.launch.mesh import make_mesh
 
     S, M, B, d = 4, 6, 2, 8
-    mesh = jax.make_mesh((S,), ("stage",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
+    mesh = make_mesh((S,), ("stage",))
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, S)
     params = {"w": jnp.stack([
@@ -43,10 +45,9 @@ PIPE_PROG = textwrap.dedent("""
 """)
 
 
-@requires_axis_type
 def test_gpipe_matches_sequential():
     out = subprocess.run([sys.executable, "-c", PIPE_PROG],
-                         capture_output=True, text=True, cwd="/root/repo",
+                         capture_output=True, text=True, cwd=REPO,
                          timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     data = json.loads(out.stdout.strip().splitlines()[-1])

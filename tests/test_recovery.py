@@ -80,6 +80,29 @@ def test_param_corruption_replays_bit_exact(tiny_setup):
         assert np.array_equal(np.asarray(a), np.asarray(b))  # BIT exact
 
 
+def test_replay_runs_the_given_replay_step(tiny_setup):
+    """With ``replay_step`` the replay rung recomputes each step through it
+    (the hot path's own executable), never through ``step_fn``."""
+    cfg, state0, step, bfn = tiny_setup
+    ran = []
+
+    def replay_step(s, st, batch):
+        ran.append(s)
+        return step(st, batch)
+
+    rt, micro = _runtime(tiny_setup, replay_step=replay_step)
+    state = _advance(step, bfn, state0, 0, 6, micro)
+    plan = sample_plan(random.Random(1), state, max_step=1, target="params")
+    fixed, ev = rt.recover(inject(state, plan),
+                           FaultReport(6, "checksum",
+                                       leaves=["params/" + plan.leaf]), 6)
+    assert ev.rung == RUNG_REPLAY
+    assert ran == [4, 5]
+    for a, b in zip(jax.tree_util.tree_leaves(fixed),
+                    jax.tree_util.tree_leaves(state)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_post_recovery_trajectory_is_fault_free(tiny_setup):
     """The strongest claim: after recovery the continued trajectory equals
     the never-faulted trajectory bit-for-bit."""

@@ -43,7 +43,8 @@ mesh8 = pytest.mark.skipif(
 
 def _ctx():
     from repro.distributed.context import DistContext
-    return DistContext.for_mesh(jax.make_mesh((4, 2), ("data", "model")))
+    from repro.launch.mesh import make_mesh
+    return DistContext.for_mesh(make_mesh((4, 2), ("data", "model")))
 
 
 def _toy_tree(ctx):
@@ -402,8 +403,9 @@ SHARDED_PROG = textwrap.dedent("""
     from repro.distributed.context import DistContext
     from repro.core.detect import ChecksumCanary
     from repro.kernels import digest as kd
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ctx = DistContext.for_mesh(mesh)
     put = lambda x, *s: jax.device_put(x, NamedSharding(mesh, P(*s)))
     k = jax.random.PRNGKey
