@@ -164,7 +164,6 @@ class CheckpointManager:
         self._thread: Optional[threading.Thread] = None
         self._last_error: Optional[BaseException] = None
         self.saves = 0
-        self.save_seconds_blocking = 0.0  # time the step path actually paid
         os.makedirs(directory, exist_ok=True)
 
     # -- step-path API ----------------------------------------------------
@@ -178,7 +177,6 @@ class CheckpointManager:
     def save(self, step: int, state) -> None:
         from repro.core.microcheckpoint import host_copy
 
-        t0 = time.perf_counter()
         # donation-safe D2H: a zero-copy host view would pin the live
         # buffers against donate_argnums for as long as the async writer
         # holds them — host_copy materialises real copies
@@ -190,7 +188,6 @@ class CheckpointManager:
             self._thread.start()
         else:
             self._write(step, host)
-        self.save_seconds_blocking += time.perf_counter() - t0
         self.saves += 1
 
     def wait(self) -> None:

@@ -58,6 +58,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.detect import ChecksumCanary, FaultReport
 from repro.kernels import digest as kdigest
 
@@ -142,7 +143,8 @@ class FusedStepFactory:
     as with the arm/check pair.
 
     Compilation accounting: ``n_compiles``/``compile_seconds`` accumulate
-    the K-executable warmup cost (the benchmarks report it); ``warm()``
+    the K-executable warmup cost (the benchmarks report it; each compile
+    is one ``compile`` span, which ``compile_seconds`` sums); ``warm()``
     forces the full rotation eagerly and returns the wall time it took.
     """
 
@@ -252,9 +254,9 @@ class FusedStepFactory:
             parity_sds = _sds(pstore.parity)
             lowered = jfn.lower(state_sds, buf_sds, table_sds, table_sds,
                                 parity_sds, *args_sds)
-        t0 = time.perf_counter()
-        compiled = lowered.compile()
-        self.compile_seconds += time.perf_counter() - t0
+        with obs.span("compile") as timed:
+            compiled = lowered.compile()
+        self.compile_seconds += timed.seconds
         self.n_compiles += 1
         return compiled, union, tuple(chk)
 
@@ -298,22 +300,26 @@ class FusedStepFactory:
         """
         new_state, aux, flag, bad, chk, ref_read = self._launch(s, state,
                                                                 args)
-        report = None
-        if flag is not None and bool(kdigest.fetch(flag)):  # ONE host sync
-            can = self.canary
-            # the commit already bumped the generation; the rows this
-            # check actually compared against live in ref_read —
-            # recovery certifies reconstructions against THEM
-            can._fault_reference = ref_read
-            # under donation the faulting input version was consumed by
-            # this very launch: the parity rung's survivors are dead, and
-            # the report says so up front (consumed=True) instead of
-            # letting the rung discover it post-hoc
-            report = FaultReport(
-                s, "checksum",
-                detail="in-step fused check",
-                resolver=lambda: can._attribute(chk, bad),
-                consumed=self.donate)
+        if flag is None:
+            return new_state, aux, None
+        with obs.span("step.flag_sync", step=s):
+            faulted = bool(kdigest.fetch(flag))            # ONE host sync
+        if not faulted:
+            return new_state, aux, None
+        can = self.canary
+        # the commit already bumped the generation; the rows this check
+        # actually compared against live in ref_read — recovery certifies
+        # reconstructions against THEM
+        can._fault_reference = ref_read
+        # under donation the faulting input version was consumed by this
+        # very launch: the parity rung's survivors are dead, and the report
+        # says so up front (consumed=True) instead of letting the rung
+        # discover it post-hoc
+        report = FaultReport(
+            s, "checksum",
+            detail="in-step fused check",
+            resolver=lambda: can._attribute(chk, bad),
+            consumed=self.donate)
         return new_state, aux, report
 
     def replay(self, s: int, state, *args):

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import digest as kdigest
 
 
@@ -103,8 +104,27 @@ class MicroCheckpointer:
         # certify exactly the bytes stored.  No device re-upload: on TPU
         # the digest rides the host DMA path, and under ``donate_argnums``
         # loops the snapshot never competes with the step for the donated
-        # buffers.
-        host = _host_copy(state)
+        # buffers.  Spans: ``snapshot`` with ``snapshot.copy`` (the host
+        # copy, with its bytes) then ``snapshot.digest`` (every digest).
+        with obs.span("snapshot", step=step):
+            with obs.span("snapshot.copy") as copy:
+                host = _host_copy(state)
+                nbytes = sum(leaf.nbytes for leaf in
+                             jax.tree_util.tree_leaves(host))
+                copy.attrs["bytes"] = nbytes
+            with obs.span("snapshot.digest"):
+                digests, shard_slices, shard_digests = self._digests(
+                    state, host)
+        snap = Snapshot(step=step, state=host, digests=digests,
+                        nbytes=nbytes, shard_slices=shard_slices,
+                        shard_digests=shard_digests)
+        self.snapshots.append(snap)
+        if len(self.snapshots) > self.keep:
+            self.snapshots.pop(0)
+
+    def _digests(self, state, host):
+        """Per-leaf host digests of the copy and, on a mesh, each leaf's
+        shard index map with per-shard digests."""
         shard_slices = shard_digests = None
         if self.ctx is not None:
             # shard-aware metadata: index maps from the LIVE shardings,
@@ -129,15 +149,7 @@ class MicroCheckpointer:
                         seen[k] = kdigest.host_checksum(hleaf[idx])
                     rows.append(seen[k])
                 shard_digests[key] = np.stack(rows)
-        snap = Snapshot(step=step, state=host,
-                        digests=kdigest.host_tree_checksums(host),
-                        nbytes=sum(leaf.nbytes for leaf in
-                                   jax.tree_util.tree_leaves(host)),
-                        shard_slices=shard_slices,
-                        shard_digests=shard_digests)
-        self.snapshots.append(snap)
-        if len(self.snapshots) > self.keep:
-            self.snapshots.pop(0)
+        return kdigest.host_tree_checksums(host), shard_slices, shard_digests
 
     def latest(self, before: Optional[int] = None) -> Optional[Snapshot]:
         cands = [s for s in self.snapshots

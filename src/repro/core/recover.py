@@ -36,7 +36,6 @@ steps lost) — the data behind the Fig-7/8 benchmarks.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -44,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.detect import ChecksumCanary, FaultReport, block_of_leaf
 from repro.core.induction import IVRegistry, RecoveryAbort
 from repro.core.microcheckpoint import MicroCheckpointer
@@ -698,7 +698,8 @@ class RecoveryRuntime:
         snap = self.micro.latest(before=step)
         if snap is None:
             raise RecoveryAbort("no snapshot available")
-        rotten = self.micro.verify(snap)
+        with obs.span("snapshot.verify"):
+            rotten = self.micro.verify(snap)
         if rotten:
             raise RecoveryAbort(f"snapshot failed verification: {rotten[:3]}")
         res = replay(self._step_at, self.batch_fn, snap.state,
@@ -782,11 +783,24 @@ class RecoveryRuntime:
         # extra transfer) so the Recovery Table lookup and the targeted
         # rungs see the corrupted leaf paths exactly as with the pair
         # protocol.
-        report.resolve()
-        ladder = list(ladder) if ladder is not None else self._ladder(report)
-        verify = verify or _default_verify
         ev = RecoveryEvent(step=step, report=report)
-        t0 = time.perf_counter()
+        # ``wall_seconds`` is the ``recover`` span, ``phase_seconds`` the
+        # ``recover.<rung>`` span of each attempted rung (repair + verify)
+        with obs.span("recover", step=step) as whole:
+            report.resolve()
+            ladder = list(ladder) if ladder is not None \
+                else self._ladder(report)
+            cand = self._walk(ladder, state, report, step,
+                              verify or _default_verify, ev)
+        ev.wall_seconds = whole.seconds
+        self.events.append(ev)
+        if not ev.recovered:
+            raise RecoveryFailed(str(report))
+        return cand, ev
+
+    def _walk(self, ladder, state, report: FaultReport, step: int,
+              verify: Callable, ev: RecoveryEvent):
+        """Try each rung in turn; the first certified repair, or None."""
         for rung in ladder:
             fn = self._RUNGS.get(rung)
             if fn is None:
@@ -794,15 +808,19 @@ class RecoveryRuntime:
             ev.attempted.append(rung)
             self._last_replayed = 0
             self._last_patched_bytes = 0
-            tr = time.perf_counter()
-            try:
-                cand, detail = fn(self, state, report, step)
-            except RecoveryAbort as e:
-                ev.phase_seconds[rung] = time.perf_counter() - tr
-                ev.report.detail += f" | {rung}: {e}"
+            bad = None
+            with obs.span("recover." + rung) as timed:
+                try:
+                    cand, detail = fn(self, state, report, step)
+                except RecoveryAbort as e:
+                    cand, detail = None, str(e)
+                else:
+                    bad = verify(cand)
+                timed.attrs["steps"] = self._last_replayed
+            ev.phase_seconds[rung] = timed.seconds
+            if cand is None:
+                ev.report.detail += f" | {rung}: {detail}"
                 continue
-            bad = verify(cand)
-            ev.phase_seconds[rung] = time.perf_counter() - tr
             if bad:
                 # exact-or-abort: the repair did not certify — escalate
                 ev.report.detail += f" | {rung}: post-verify failed {bad[:2]}"
@@ -811,13 +829,9 @@ class RecoveryRuntime:
             ev.recovered = True
             ev.steps_replayed = self._last_replayed
             ev.bytes_moved = self._last_patched_bytes
-            ev.wall_seconds = time.perf_counter() - t0
             ev.report.detail += f" | {rung}: {detail}"
-            self.events.append(ev)
-            return cand, ev
-        ev.wall_seconds = time.perf_counter() - t0
-        self.events.append(ev)
-        raise RecoveryFailed(str(report))
+            return cand
+        return None
 
     def _ladder(self, report: FaultReport) -> List[str]:
         """Choose the ladder from the Recovery Table (or the default)."""

@@ -27,6 +27,8 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from repro import obs
+
 
 @dataclass
 class ReplayResult:
@@ -74,7 +76,12 @@ def replay(step_at: Callable, batch_fn: Callable, snapshot_state,
     reference survives (donated loops).
     """
     assert to_step >= from_step, (from_step, to_step)
-    state = device_put_like(snapshot_state, like_state, shardings)
+    nbytes = sum(leaf.nbytes
+                 for leaf in jax.tree_util.tree_leaves(snapshot_state))
+    with obs.span("snapshot.upload", bytes=nbytes):
+        state = device_put_like(snapshot_state, like_state, shardings)
+        # timed to the upload's end, not its enqueue
+        jax.block_until_ready(state)
     for s in range(from_step, to_step):
         state, _ = step_at(s, state, batch_fn(s))
         if on_step is not None:

@@ -22,7 +22,6 @@ from __future__ import annotations
 import argparse
 import json
 import random
-import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -30,6 +29,7 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.core import (
@@ -93,6 +93,17 @@ def batch_for(cfg, pipe, step):
     if m.patch_dim:
         batch = pipe.with_patches(batch, 16, m.patch_dim, step)
     return batch
+
+
+def _rearm(canary, pstore, state, step: int) -> None:
+    """Re-digest the canary's reference and rebuild the parity over a state
+    the loop did not step into (recovered or restored)."""
+    if canary is not None:
+        with obs.span("canary.refresh", step=step):
+            canary.refresh(state)
+    if pstore is not None:
+        with obs.span("parity.rebuild", step=step):
+            pstore.rebuild(state, step)
 
 
 def train(cfg, *, steps: int, global_batch: int, seq_len: int,
@@ -174,240 +185,271 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
     highest surviving data row (no process actually dies — the "dead"
     devices are simply never read again).
     """
-    key = jax.random.PRNGKey(seed)
-    pipe = TokenPipeline(cfg.model.vocab_size, seq_len, global_batch,
-                         seed=seed)
-    ctx = make_context(mesh)
-    state = make_train_state(cfg, key, global_batch=global_batch)
-    raw_step = make_train_step(cfg, global_batch=global_batch)
-    raw_bfn = lambda s: batch_for(cfg, pipe, s)
-    # THE mesh-binding recipe (shardings + device_put + layout pin +
-    # batch placement) lives in launch/specs.bind_state — the elastic
-    # remesh path re-runs the SAME recipe against the degraded context
-    state, raw_step, bfn, shardings = bind_state(
-        ctx, cfg, state, raw_step, raw_bfn)
-    step_fn = jax.jit(raw_step, donate_argnums=(0,) if donate else ())
+    with obs.tally() as tally:
+        with obs.span("setup", step=0):
+            key = jax.random.PRNGKey(seed)
+            pipe = TokenPipeline(cfg.model.vocab_size, seq_len,
+                                 global_batch, seed=seed)
+            ctx = make_context(mesh)
+            with obs.span("setup.state"):
+                state = make_train_state(cfg, key,
+                                         global_batch=global_batch)
+                raw_step = make_train_step(cfg, global_batch=global_batch)
+                raw_bfn = lambda s: batch_for(cfg, pipe, s)
+                # THE mesh-binding recipe (shardings + device_put + layout
+                # pin + batch placement) lives in launch/specs.bind_state —
+                # the elastic remesh path re-runs the SAME recipe against
+                # the degraded context
+                state, raw_step, bfn, shardings = bind_state(
+                    ctx, cfg, state, raw_step, raw_bfn)
+            step_fn = jax.jit(raw_step,
+                              donate_argnums=(0,) if donate else ())
 
-    micro = MicroCheckpointer(interval=snapshot_interval, ctx=ctx)
-    ckpt = CheckpointManager(checkpoint_dir,
-                             interval=checkpoint_interval) \
-        if checkpoint_dir else None
-    canary = ChecksumCanary(state, n_slices=canary_slices, ctx=ctx) \
-        if detectors else None
-    pstore = None
-    if parity:
-        if canary is None:
-            raise ValueError("parity requires detectors=True (parity "
-                             "maintenance rides the canary's launches and "
-                             "reconstruction certifies against its digests)")
-        # elastic hard loss needs row-safe parity placement: the buffer
-        # lives on the non-batch mesh axes so a dead data row never takes
-        # the parity covering its own shards down with it
-        pstore = ParityStore(state, ctx=ctx, row_safe=elastic)
-        pstore.build(state)
-        canary.attach_parity(pstore)
-    if triage and canary is None:
-        raise ValueError("triage requires detectors=True (rung 0 "
-                         "classifies against the canary's digest pair)")
-    emgr = None
-    elastic_hook = None
-    if elastic:
-        if ctx is None:
-            raise ValueError("elastic requires mesh='dp,tp' (a hard loss "
-                             "shrinks the data axis of a device mesh)")
-        if pstore is None:
-            raise ValueError("elastic requires parity=True (dead rows' "
-                             "shards are rebuilt from the XOR parity)")
-        from repro.launch.elastic import ElasticManager
-        emgr = ElasticManager(ctx, verbose=verbose)
-        elastic_hook = emgr.hook(raw_step=raw_step, cfg=cfg,
-                                 batch_fn=raw_bfn, canary=canary,
-                                 pstore=pstore, donate=donate)
-    if kill_row_at is not None and emgr is None:
-        raise ValueError("kill_row_at requires elastic=True")
-    fused = None
-    if fused_detect:
-        if canary is None:
-            raise ValueError("fused_detect requires detectors=True "
-                             "(the canary IS the in-step detector)")
-        # the factory jits the RAW step together with the canary check/arm;
-        # replay runs through the same executables (``fused.replay``), so
-        # a replayed step reproduces the hot path's bits
-        fused = canary.fuse_into_step(raw_step, donate=donate,
-                                      warm=fused_warm)
-        if fused_warm == "eager":
-            # compile all K rotation executables BEFORE the loop so the
-            # first step's wall time doesn't absorb them ('lazy' keeps
-            # the documented pay-per-rotation behaviour)
-            fused.warm(state, bfn(0))
-    runtime = RecoveryRuntime(
-        step_fn=step_fn,
-        batch_fn=bfn, iv_registry=promote(cfg, global_batch), micro=micro,
-        parity=pstore, checkpoint=ckpt.loader(state) if ckpt else None,
-        donated=donate, shardings=shardings, canary=canary, triage=triage,
-        elastic=elastic_hook,
-        replay_step=fused.replay if fused is not None else None)
+            micro = MicroCheckpointer(interval=snapshot_interval, ctx=ctx)
+            ckpt = CheckpointManager(checkpoint_dir,
+                                     interval=checkpoint_interval) \
+                if checkpoint_dir else None
+            canary = ChecksumCanary(state, n_slices=canary_slices,
+                                    ctx=ctx) if detectors else None
+            pstore = None
+            if parity:
+                if canary is None:
+                    raise ValueError(
+                        "parity requires detectors=True (parity "
+                        "maintenance rides the canary's launches and "
+                        "reconstruction certifies against its digests)")
+                # elastic hard loss needs row-safe parity placement: the
+                # buffer lives on the non-batch mesh axes so a dead data
+                # row never takes the parity covering its own shards down
+                # with it
+                pstore = ParityStore(state, ctx=ctx, row_safe=elastic)
+                with obs.span("setup.parity"):
+                    pstore.build(state)
+                canary.attach_parity(pstore)
+            if triage and canary is None:
+                raise ValueError(
+                    "triage requires detectors=True (rung 0 classifies "
+                    "against the canary's digest pair)")
+            emgr = None
+            elastic_hook = None
+            if elastic:
+                if ctx is None:
+                    raise ValueError(
+                        "elastic requires mesh='dp,tp' (a hard loss "
+                        "shrinks the data axis of a device mesh)")
+                if pstore is None:
+                    raise ValueError(
+                        "elastic requires parity=True (dead rows' "
+                        "shards are rebuilt from the XOR parity)")
+                from repro.launch.elastic import ElasticManager
+                emgr = ElasticManager(ctx, verbose=verbose)
+                elastic_hook = emgr.hook(raw_step=raw_step, cfg=cfg,
+                                         batch_fn=raw_bfn, canary=canary,
+                                         pstore=pstore, donate=donate)
+            if kill_row_at is not None and emgr is None:
+                raise ValueError("kill_row_at requires elastic=True")
+            fused = None
+            if fused_detect:
+                if canary is None:
+                    raise ValueError(
+                        "fused_detect requires detectors=True "
+                        "(the canary IS the in-step detector)")
+                # the factory jits the RAW step together with the canary
+                # check/arm; replay runs through the same executables
+                # (``fused.replay``), so a replayed step reproduces the
+                # hot path's bits
+                fused = canary.fuse_into_step(raw_step, donate=donate,
+                                              warm=fused_warm)
+                if fused_warm == "eager":
+                    # compile all K rotation executables BEFORE the loop
+                    # so the first step's wall time doesn't absorb them
+                    # ('lazy' keeps the documented pay-per-rotation
+                    # behaviour)
+                    with obs.span("setup.compile"):
+                        fused.warm(state, bfn(0))
+            runtime = RecoveryRuntime(
+                step_fn=step_fn, batch_fn=bfn,
+                iv_registry=promote(cfg, global_batch), micro=micro,
+                parity=pstore,
+                checkpoint=ckpt.loader(state) if ckpt else None,
+                donated=donate, shardings=shardings, canary=canary,
+                triage=triage, elastic=elastic_hook,
+                replay_step=fused.replay if fused is not None else None)
 
-    rng = random.Random(seed + 7)
-    rep = LoopReport()
-    # bounded: the spike trap reads only the last LOSS_WINDOW losses
-    # (rep.losses keeps the full telemetry trace)
-    history = deque(maxlen=LOSS_WINDOW)
-    last_inject = -1
+        rng = random.Random(seed + 7)
+        rep = LoopReport()
+        # bounded: the spike trap reads only the last LOSS_WINDOW losses
+        # (rep.losses keeps the full telemetry trace)
+        history = deque(maxlen=LOSS_WINDOW)
+        last_inject = -1
 
-    s = 0
-    while s < steps:
-        if donate and canary is not None and fused is None:
-            # donated hot path, arm half: digest slice s%K of the buffer
-            # the previous step just produced (one launch, no sync);
-            # check(s) below verifies the SAME slice of the SAME buffer
-            # version right before the step consumes it
-            canary.arm_current(s, state)
+        s = 0
+        while s < steps:
+            if donate and canary is not None and fused is None:
+                # donated hot path, arm half: digest slice s%K of the buffer
+                # the previous step just produced (one launch, no sync);
+                # check(s) below verifies the SAME slice of the SAME buffer
+                # version right before the step consumes it
+                canary.arm_current(s, state)
 
-        micro.record_iv(s, state["iv"])
-        micro.maybe_snapshot(s, state)
-        if ckpt:
-            ckpt.maybe_save(s, state)
+            micro.record_iv(s, state["iv"])
+            micro.maybe_snapshot(s, state)
+            if ckpt:
+                ckpt.maybe_save(s, state)
 
-        # -- adversary: single-bit flip before the step (evaluation only;
-        #    once per step — a recovery retry must not be re-hit) --
-        if inject_every and s and s % inject_every == 0 and last_inject != s:
-            plan = sample_plan(rng, state, max_step=1, target=inject_target)
-            state = inject(state, plan)
-            rep.faults_injected += 1
-            last_inject = s
+            # -- adversary: single-bit flip before the step (evaluation
+            #    only; once per step — a recovery retry must not be
+            #    re-hit) --
+            if inject_every and s and s % inject_every == 0 \
+                    and last_inject != s:
+                with obs.span("inject", step=s):
+                    plan = sample_plan(rng, state, max_step=1,
+                                       target=inject_target)
+                    state = inject(state, plan)
+                rep.faults_injected += 1
+                last_inject = s
 
-        report = None
-        if emgr is not None and kill_row_at is not None \
-                and s == kill_row_at and not emgr.dead:
-            # chaos drill: the highest surviving data row "dies" here —
-            # an external hard-loss report routes straight to the remesh
-            # rung; the dead devices are never read again
-            target = emgr.kill_target()
-            report = FaultReport(
-                s, "external", lost_rows=(target,),
-                detail=f"simulated hard loss of data row {target}")
-        if report is None and donate and canary is not None \
-                and fused is None:
-            # donated hot path, check half: the step is about to CONSUME
-            # the state buffers, so this is their last readable moment —
-            # one launch + ONE scalar sync verifies slice s%K against the
-            # generation armed at the top of this loop body
-            report = canary.check(s, state)
-
-        if report is None:
-            t0 = time.perf_counter()
-            if fused is not None:
-                # in-step fused canary: the check of slice s%K of the
-                # input state and the arm of slice (s+1)%K of the output
-                # ride the step's own launch — 1 combined launch + 1
-                # scalar sync, donated or not; on a report the new state
-                # is corrupt-derived and discarded below
-                new_state, metrics, report = fused.step(s, state, bfn(s))
-            else:
-                new_state, metrics = step_fn(state, bfn(s))
-            jax.block_until_ready(metrics["loss"])
-            rep.step_seconds.append(time.perf_counter() - t0)
-
-            if detectors and report is None:
-                report = trap_nonfinite(s, metrics) or \
-                    trap_loss_spike(s, metrics, history)
-                if report is None and not donate and canary is not None \
-                        and fused is None:
-                    # fused rotating canary — ONE launch + ONE scalar sync:
-                    # verify the pre-step state's slice (armed at the end
-                    # of an earlier step: was the state rotted while at
-                    # rest / in use?) and digest the fresh output's
-                    # next-check slice
-                    report = canary.check_and_arm(s, state, new_state)
+            report = None
+            if emgr is not None and kill_row_at is not None \
+                    and s == kill_row_at and not emgr.dead:
+                # chaos drill: the highest surviving data row "dies" here —
+                # an external hard-loss report routes straight to the remesh
+                # rung; the dead devices are never read again
+                target = emgr.kill_target()
+                report = FaultReport(
+                    s, "external", lost_rows=(target,),
+                    detail=f"simulated hard loss of data row {target}")
+            if report is None and donate and canary is not None \
+                    and fused is None:
+                # donated hot path, check half: the step is about to CONSUME
+                # the state buffers, so this is their last readable moment —
+                # one launch + ONE scalar sync verifies slice s%K against the
+                # generation armed at the top of this loop body
+                report = canary.check(s, state)
 
             if report is None:
-                state = new_state
-                loss = float(metrics["loss"])
-                history.append(loss)
-                rep.losses.append(loss)
-                if verbose and s % max(1, steps // 10) == 0:
-                    print(f"[train] step {s:5d} loss {loss:.4f}")
-                s += 1
-                rep.steps += 1
-                continue
+                with obs.span("step", step=s) as timed:
+                    with obs.span("step.batch"):
+                        batch = bfn(s)
+                    if fused is not None:
+                        # in-step fused canary: the check of slice s%K of
+                        # the input state and the arm of slice (s+1)%K of
+                        # the output ride the step's own launch — 1
+                        # combined launch + 1 scalar sync (its span is
+                        # ``step.flag_sync``), donated or not; on a report
+                        # the new state is corrupt-derived and discarded
+                        # below
+                        new_state, metrics, report = fused.step(s, state,
+                                                                batch)
+                    else:
+                        new_state, metrics = step_fn(state, batch)
+                    jax.block_until_ready(metrics["loss"])
+                rep.step_seconds.append(timed.seconds)
 
-        # ---------------- recovery path (off hot path) -------------------
-        rep.faults_detected += 1
-        # in-step fused reports defer leaf attribution to the fault path —
-        # materialise it here so the log names the corrupted leaves
-        # exactly like the unfused paths (no-op for resolved reports)
-        report.resolve()
-        if verbose:
-            print(f"[train] FAULT at step {s}: {report}")
-        try:
-            t0 = time.perf_counter()
-            state, ev = runtime.recover(state, report, s)
-            rep.faults_recovered += 1
-            rep.recovery_ms.append(1e3 * (time.perf_counter() - t0))
-            resume = getattr(runtime, "pending_remesh", None)
-            if resume is not None:
-                # hard loss: the remesh rung already rebuilt EVERYTHING
-                # against the degraded mesh — swap the loop's working set
-                # wholesale; canary/parity are freshly armed (no refresh/
-                # rebuild: they'd re-digest what was just certified)
-                runtime.pending_remesh = None
-                ctx = resume.ctx
-                state = resume.state
-                step_fn = resume.step       # AOT-compiled: cannot retrace
-                raw_step = resume.raw_step
-                bfn = resume.bfn
-                shardings = resume.shardings
-                canary = resume.canary
-                pstore = resume.pstore
-                micro = MicroCheckpointer(interval=snapshot_interval,
-                                          ctx=ctx)
-                runtime.micro = micro
-                # re-close the hook over the new artifacts so a SECOND
-                # loss composes (emgr.ctx already advanced)
-                runtime.elastic = emgr.hook(
-                    raw_step=raw_step, cfg=cfg, batch_fn=raw_bfn,
-                    canary=canary, pstore=pstore, donate=donate)
-                if fused is not None:
-                    # the old fused executables were evicted with the old
-                    # mesh; rebuild against the fresh canary
-                    fused = canary.fuse_into_step(raw_step, donate=donate,
-                                                  warm=fused_warm)
-                    if fused_warm == "eager":
-                        fused.warm(state, bfn(s))
-                    runtime.replay_step = fused.replay
-                rep.elastic_events.append(resume.event.to_dict())
-            else:
-                if canary is not None:
-                    canary.refresh(state)
-                if pstore is not None:
-                    # recovery may have produced a whole new state version
-                    # (replay/checkpoint rungs); re-anchor the parity to it
-                    pstore.rebuild(state, s)
-            if verbose:
-                print(f"[train] recovered via {ev.rung} in "
-                      f"{rep.recovery_ms[-1]:.1f} ms")
-        except RecoveryFailed:
-            if ckpt is None:
-                raise
-            state, ck_step = ckpt.restore(state)
-            s = ck_step
-            if canary is not None:
-                # restored state == new reference; stale digests would
-                # fire a spurious checksum fault on the next step
-                canary.refresh(state)
-            if pstore is not None:
-                pstore.rebuild(state, ck_step)
-            if verbose:
-                print(f"[train] cold restore to step {ck_step}")
+                if detectors and report is None:
+                    report = trap_nonfinite(s, metrics) or \
+                        trap_loss_spike(s, metrics, history)
+                    if report is None and not donate and canary is not None \
+                            and fused is None:
+                        # fused rotating canary — ONE launch + ONE scalar
+                        # sync: verify the pre-step state's slice (armed at the end
+                        # of an earlier step: was the state rotted while at
+                        # rest / in use?) and digest the fresh output's
+                        # next-check slice
+                        report = canary.check_and_arm(s, state, new_state)
 
-    if ckpt:
-        ckpt.wait()
-    out = rep.summary()
-    out["recovery"] = runtime.summary()
-    if ctx is not None:
-        out["mesh"] = {"shape": dict(ctx.mesh.shape),
-                       "devices": ctx.n_devices}
+                if report is None:
+                    state = new_state
+                    loss = float(metrics["loss"])
+                    history.append(loss)
+                    rep.losses.append(loss)
+                    if verbose and s % max(1, steps // 10) == 0:
+                        print(f"[train] step {s:5d} loss {loss:.4f}")
+                    s += 1
+                    rep.steps += 1
+                    continue
+
+            # ---------------- recovery path (off hot path) ----------------
+            # one ``fault`` span from the report's arrival to the loop's
+            # resume: the ladder, the canary refresh and the parity rebuild
+            rep.faults_detected += 1
+            with obs.span("fault", step=s):
+                # in-step fused reports defer leaf attribution to the fault
+                # path — materialise it here so the log names the corrupted
+                # leaves exactly like the unfused paths (no-op for resolved
+                # reports)
+                report.resolve()
+                if verbose:
+                    print(f"[train] FAULT at step {s}: {report}")
+                try:
+                    state, ev = runtime.recover(state, report, s)
+                    rep.faults_recovered += 1
+                    rep.recovery_ms.append(1e3 * ev.wall_seconds)
+                    resume = getattr(runtime, "pending_remesh", None)
+                    if resume is not None:
+                        # hard loss: the remesh rung already rebuilt
+                        # EVERYTHING against the degraded mesh — swap the
+                        # loop's working set wholesale; canary/parity are
+                        # freshly armed (no refresh/rebuild: they'd
+                        # re-digest what was just certified)
+                        runtime.pending_remesh = None
+                        ctx = resume.ctx
+                        state = resume.state
+                        step_fn = resume.step   # AOT-compiled: no retrace
+                        raw_step = resume.raw_step
+                        bfn = resume.bfn
+                        shardings = resume.shardings
+                        canary = resume.canary
+                        pstore = resume.pstore
+                        micro = MicroCheckpointer(interval=snapshot_interval,
+                                                  ctx=ctx)
+                        runtime.micro = micro
+                        # re-close the hook over the new artifacts so a
+                        # SECOND loss composes (emgr.ctx already advanced)
+                        runtime.elastic = emgr.hook(
+                            raw_step=raw_step, cfg=cfg, batch_fn=raw_bfn,
+                            canary=canary, pstore=pstore, donate=donate)
+                        if fused is not None:
+                            # the old fused executables were evicted with
+                            # the old mesh; rebuild against the fresh canary
+                            fused = canary.fuse_into_step(
+                                raw_step, donate=donate, warm=fused_warm)
+                            if fused_warm == "eager":
+                                fused.warm(state, bfn(s))
+                            runtime.replay_step = fused.replay
+                        rep.elastic_events.append(resume.event.to_dict())
+                    else:
+                        # recovery may have produced a whole new state
+                        # version (replay/checkpoint rungs): re-digest it
+                        # and re-anchor the parity to it
+                        _rearm(canary, pstore, state, s)
+                    if verbose:
+                        print(f"[train] recovered via {ev.rung} in "
+                              f"{rep.recovery_ms[-1]:.1f} ms")
+                except RecoveryFailed:
+                    if ckpt is None:
+                        raise
+                    state, ck_step = ckpt.restore(state)
+                    s = ck_step
+                    # restored state == new reference; stale digests would
+                    # fire a spurious checksum fault on the next step
+                    _rearm(canary, pstore, state, ck_step)
+                    if verbose:
+                        print(f"[train] cold restore to step {ck_step}")
+                # the span ends once the device holds what the loop resumes
+                # from, not when the work is enqueued
+                jax.block_until_ready((
+                    state, canary.reference if canary is not None else None,
+                    pstore.parity if pstore is not None else None))
+
+        if ckpt:
+            ckpt.wait()
+        out = rep.summary()
+        out["recovery"] = runtime.summary()
+        if ctx is not None:
+            out["mesh"] = {"shape": dict(ctx.mesh.shape),
+                           "devices": ctx.n_devices}
+    out["spans"] = tally.summary()
     return (out, state) if return_state else out
 
 
