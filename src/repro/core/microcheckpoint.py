@@ -10,6 +10,13 @@ is evaluable at recovery time.  Our two-tier analogue:
   a full train-state copy + per-leaf digests, giving the replay rung a
   nearby anchor.  No disk I/O on the recovery path — that is the entire
   near-zero-downtime claim vs classic C/R.
+
+Off a mesh the snapshot's certificate is computed on the device: the
+per-leaf Fletcher digests of the live state come from the canary's own
+``DigestPlan``, in leaf groups of at most ``DIGEST_GROUP_BYTES`` packed
+bytes, and the restore re-digests the UPLOADED tree against them, so what
+is replayed is exactly what was digested.  Mesh snapshots keep their
+per-(leaf, shard) host digests (``shard_digests``).
 """
 
 from __future__ import annotations
@@ -24,6 +31,14 @@ import numpy as np
 
 from repro import obs
 from repro.kernels import digest as kdigest
+
+#: cap on the packed int32 bytes one device digest program covers.  A
+#: whole-state digest needs a packing buffer the size of the packed state
+#: and a row-digest output padded to as much again; grouping leaves bounds
+#: that transient HBM and keeps the number of group programs small (a
+#: 1.8 GB bf16/f32 training state packs to ~2.2 GB: twelve groups).
+#: A leaf larger than the cap forms a group alone.
+DIGEST_GROUP_BYTES = 256 << 20
 
 
 def host_copy(tree):
@@ -42,6 +57,29 @@ def host_copy(tree):
 
 
 _host_copy = host_copy
+
+
+def digest_groups(plan) -> Tuple[Tuple[Tuple[int, ...], ...], int]:
+    """Contiguous runs of ``plan``'s leaves (canonical order) whose packed
+    bytes stay within ``DIGEST_GROUP_BYTES``, and the bytes their packing
+    buffers stream.  Concatenating the groups' tables gives the whole
+    table in canonical order."""
+    groups: List[Tuple[int, ...]] = []
+    cur: List[int] = []
+    size = 0
+    for sp in plan.specs:
+        b = sp.n_rows * kdigest.LANES * 4
+        if cur and size + b > DIGEST_GROUP_BYTES:
+            groups.append(tuple(cur))
+            cur, size = [], 0
+        cur.append(sp.index)
+        size += b
+    if cur:
+        groups.append(tuple(cur))
+    tile = kdigest.TILE_ROWS
+    nbytes = sum(-(-sum(plan.specs[i].n_rows for i in g) // tile) * tile
+                 for g in groups) * kdigest.LANES * 4
+    return tuple(groups), nbytes
 
 
 @dataclass
@@ -70,8 +108,9 @@ class MicroCheckpointer:
     (``Snapshot.shard_slices``/``shard_digests``), so recovery can verify
     and restore individual (leaf, shard) units instead of whole states.
     The host copy itself is unchanged (one DMA read of the live state);
-    the shard digests are a second host-side hashing pass over the same
-    bytes, off the hot path."""
+    on a mesh every digest is a host-side hashing pass over the copy's
+    bytes, off the hot path.  Without a mesh the per-leaf digests are
+    device digests of the live state (``_device_digests``)."""
 
     def __init__(self, interval: int = 8, keep: int = 2, ctx=None):
         self.interval = max(1, interval)
@@ -79,6 +118,8 @@ class MicroCheckpointer:
         self.ctx = ctx if (ctx is not None and ctx.enabled) else None
         self.snapshots: List[Snapshot] = []
         self.iv_log: Dict[int, Dict[str, int]] = {}
+        #: ``digest_groups`` of each DigestPlan this checkpointer digested
+        self._groups: Dict[kdigest.DigestPlan, Tuple] = {}
 
     # -- per-step (bytes) ----------------------------------------------------
 
@@ -98,23 +139,28 @@ class MicroCheckpointer:
         return True
 
     def snapshot(self, step: int, state) -> None:
-        # ONE read of the live state: the host copy is the only
-        # device→host movement; digests are computed FROM THAT COPY on the
-        # host (numpy uint32 wraparound, bit-identical to the kernel) and
-        # certify exactly the bytes stored.  No device re-upload: on TPU
-        # the digest rides the host DMA path, and under ``donate_argnums``
-        # loops the snapshot never competes with the step for the donated
-        # buffers.  Spans: ``snapshot`` with ``snapshot.copy`` (the host
-        # copy, with its bytes) then ``snapshot.digest`` (every digest).
+        # Off a mesh the digests are device digests of the live state,
+        # taken before the host copy: both read the buffer version the
+        # next (donating) step consumes.  On a mesh
+        # they are host digests of the copy, per leaf and per shard.
+        # Spans: ``snapshot`` over ``snapshot.digest`` (with the packed
+        # ``bytes`` it digested and the ``groups`` it launched, off a mesh)
+        # and ``snapshot.copy`` (the host copy, with its bytes).
+        shard_slices = shard_digests = None
         with obs.span("snapshot", step=step):
+            if self.ctx is None:
+                with obs.span("snapshot.digest") as timed:
+                    digests, timed.attrs["bytes"], timed.attrs["groups"] = \
+                        self._device_digests(state)
             with obs.span("snapshot.copy") as copy:
                 host = _host_copy(state)
                 nbytes = sum(leaf.nbytes for leaf in
                              jax.tree_util.tree_leaves(host))
                 copy.attrs["bytes"] = nbytes
-            with obs.span("snapshot.digest"):
-                digests, shard_slices, shard_digests = self._digests(
-                    state, host)
+            if self.ctx is not None:
+                with obs.span("snapshot.digest"):
+                    digests, shard_slices, shard_digests = \
+                        self._host_digests(state, host)
         snap = Snapshot(step=step, state=host, digests=digests,
                         nbytes=nbytes, shard_slices=shard_slices,
                         shard_digests=shard_digests)
@@ -122,33 +168,52 @@ class MicroCheckpointer:
         if len(self.snapshots) > self.keep:
             self.snapshots.pop(0)
 
-    def _digests(self, state, host):
-        """Per-leaf host digests of the copy and, on a mesh, each leaf's
-        shard index map with per-shard digests."""
-        shard_slices = shard_digests = None
-        if self.ctx is not None:
-            # shard-aware metadata: index maps from the LIVE shardings,
-            # digests from the host copy's bytes (never re-read the
-            # device) — per (leaf, shard), in mesh-flat shard order
-            shard_slices, shard_digests = {}, {}
-            flat_live = jax.tree_util.tree_flatten_with_path(state)[0]
-            flat_host = jax.tree_util.tree_leaves(host)
-            for (path, live), hleaf in zip(flat_live, flat_host):
-                key = kdigest.leaf_key(path)
-                idxs = kdigest.shard_indices(live)
-                shard_slices[key] = idxs
-                # hash each DISTINCT slice once: a replicated leaf maps
-                # every shard to the same full-leaf index, and hashing it
-                # D times would make snapshots O(replicated_bytes x D)
-                seen: Dict[Tuple, np.ndarray] = {}
-                rows = []
-                for idx in idxs:
-                    k = tuple((s.start, s.stop, s.step)
-                              if isinstance(s, slice) else s for s in idx)
-                    if k not in seen:
-                        seen[k] = kdigest.host_checksum(hleaf[idx])
-                    rows.append(seen[k])
-                shard_digests[key] = np.stack(rows)
+    def _device_digests(self, tree) -> Tuple[Dict[str, np.ndarray], int,
+                                             int]:
+        """Per-leaf Fletcher digests of a device tree, keyed by path, with
+        the packed bytes digested and the programs launched: one
+        ``DigestPlan.digest_subset`` program per leaf group
+        (``digest_groups``, cached per plan), run one after another, and
+        ONE host fetch of the concatenated table."""
+        plan = kdigest.plan_for(tree)
+        cached = self._groups.get(plan)
+        if cached is None:
+            cached = self._groups[plan] = digest_groups(plan)
+        groups, nbytes = cached
+        # one group in flight: a program's packing buffer is allocated
+        # when it is enqueued, so enqueueing every group ahead would hold
+        # the whole packed state at once
+        table = kdigest.fetch(jnp.concatenate(
+            [plan.digest_subset(tree, g).block_until_ready()
+             for g in groups]))
+        return ({k: table[i] for i, k in enumerate(plan.keys)}, nbytes,
+                len(groups))
+
+    def _host_digests(self, state, host):
+        """Mesh snapshots: per-leaf host digests of the copy, and each
+        leaf's shard index map with per-shard digests."""
+        # index maps from the LIVE shardings, digests from the host copy's
+        # bytes (never re-read the device) — per (leaf, shard), in
+        # mesh-flat shard order
+        shard_slices, shard_digests = {}, {}
+        flat_live = jax.tree_util.tree_flatten_with_path(state)[0]
+        flat_host = jax.tree_util.tree_leaves(host)
+        for (path, live), hleaf in zip(flat_live, flat_host):
+            key = kdigest.leaf_key(path)
+            idxs = kdigest.shard_indices(live)
+            shard_slices[key] = idxs
+            # hash each DISTINCT slice once: a replicated leaf maps
+            # every shard to the same full-leaf index, and hashing it
+            # D times would make snapshots O(replicated_bytes x D)
+            seen: Dict[Tuple, np.ndarray] = {}
+            rows = []
+            for idx in idxs:
+                k = tuple((s.start, s.stop, s.step)
+                          if isinstance(s, slice) else s for s in idx)
+                if k not in seen:
+                    seen[k] = kdigest.host_checksum(hleaf[idx])
+                rows.append(seen[k])
+            shard_digests[key] = np.stack(rows)
         return kdigest.host_tree_checksums(host), shard_slices, shard_digests
 
     def latest(self, before: Optional[int] = None) -> Optional[Snapshot]:
@@ -156,12 +221,22 @@ class MicroCheckpointer:
                  if before is None or s.step <= before]
         return cands[-1] if cands else None
 
-    def verify(self, snap: Snapshot) -> List[str]:
-        """Digest-verify a snapshot before trusting it for replay
-        (exact-or-abort: a rotted snapshot must not silently replay).
-        Entirely host-side — the stored bytes are hashed where they live,
-        with no device upload."""
-        return kdigest.host_verify_tree(snap.state, snap.digests)
+    def verify(self, snap: Snapshot, state) -> List[str]:
+        """Leaf paths whose digest differs from the snapshot's — the replay
+        rung's exact-or-abort gate (a rotted snapshot must not silently
+        replay).  Off a mesh ``state`` is the snapshot as UPLOADED, and
+        its device digests are compared, so a fault in the copy down, in
+        host RAM or in the upload is caught; on a mesh the stored host
+        bytes are re-hashed.  Span ``snapshot.verify`` (with ``bytes`` and
+        ``groups`` off a mesh)."""
+        with obs.span("snapshot.verify") as timed:
+            if self.ctx is not None:
+                return kdigest.host_verify_tree(snap.state, snap.digests)
+            current, timed.attrs["bytes"], timed.attrs["groups"] = \
+                self._device_digests(state)
+        return sorted(k for k, ref in snap.digests.items()
+                      if k not in current
+                      or not np.array_equal(current[k], ref))
 
     def verify_shards(self, snap: Snapshot,
                       shards: Dict[str, List[int]]) -> List[str]:

@@ -60,7 +60,7 @@ from repro.core.recovery_table import (
     RUNG_TRIAGE,
     RecoveryTable,
 )
-from repro.core.replay import device_put_like, replay
+from repro.core.replay import replay, upload
 from repro.kernels import digest as kdigest
 from repro.kernels import ops as kops
 from repro.optim.optimizers import QBLOCK
@@ -694,18 +694,16 @@ class RecoveryRuntime:
         return self.step_fn(state, batch)
 
     def _rung_replay(self, state, report: FaultReport, step: int):
-        """Replay from the newest digest-verified snapshot ≤ step."""
+        """Replay from the newest snapshot ≤ step, uploaded first and then
+        digest-verified as uploaded (exact-or-abort)."""
         snap = self.micro.latest(before=step)
         if snap is None:
             raise RecoveryAbort("no snapshot available")
-        with obs.span("snapshot.verify"):
-            rotten = self.micro.verify(snap)
+        start = self._upload(snap.state, state)
+        rotten = self.micro.verify(snap, start)
         if rotten:
             raise RecoveryAbort(f"snapshot failed verification: {rotten[:3]}")
-        res = replay(self._step_at, self.batch_fn, snap.state,
-                     snap.step, step,
-                     like_state=None if self.donated else state,
-                     shardings=self.shardings)
+        res = replay(self._step_at, self.batch_fn, start, snap.step, step)
         self._last_replayed = res.steps_replayed
         return res.state, f"replayed {res.steps_replayed} steps from {snap.step}"
 
@@ -714,11 +712,16 @@ class RecoveryRuntime:
         if self.checkpoint is None:
             raise RecoveryAbort("no checkpoint loader configured")
         ck_state, ck_step = self.checkpoint()
-        res = replay(self._step_at, self.batch_fn, ck_state, ck_step, step,
-                     like_state=None if self.donated else state,
-                     shardings=self.shardings)
+        res = replay(self._step_at, self.batch_fn,
+                     self._upload(ck_state, state), ck_step, step)
         self._last_replayed = res.steps_replayed
         return res.state, f"restored step {ck_step} + replayed to {step}"
+
+    def _upload(self, host_state, state):
+        """A host state on the device, placed like the live ``state`` (or
+        by ``shardings`` when donation left no live buffers)."""
+        return upload(host_state, like_state=None if self.donated else state,
+                      shardings=self.shardings)
 
     def _rung_remesh(self, state, report: FaultReport, step: int):
         """HARD loss: devices are gone, not corrupt — shrink the mesh and

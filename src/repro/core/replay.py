@@ -15,17 +15,16 @@ compiled inside the in-step fused canary program round differently, so
 the replay must be handed the hot path's own executables.
 
 The snapshot plays the paper's "terminal values" role: the micro-checkpointer
-guarantees (by digest verification — our liveness analysis) that the replay
-inputs are intact before we trust them.
+guarantees (by digest verification of the uploaded snapshot — our liveness
+analysis) that the replay inputs are intact before we trust them.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Optional
 
 import jax
-import numpy as np
 
 from repro import obs
 
@@ -63,25 +62,28 @@ def device_put_like(host_state, like_state=None, shardings=None):
     return jax.tree_util.tree_map(put, host_state, like_state)
 
 
-def replay(step_at: Callable, batch_fn: Callable, snapshot_state,
-           from_step: int, to_step: int, *, like_state=None,
-           shardings=None, on_step: Optional[Callable] = None
-           ) -> ReplayResult:
+def upload(host_state, *, like_state=None, shardings=None):
+    """``device_put_like`` timed to the upload's end, not its enqueue, in
+    the span ``snapshot.upload`` (with the ``bytes`` moved)."""
+    nbytes = sum(leaf.nbytes
+                 for leaf in jax.tree_util.tree_leaves(host_state))
+    with obs.span("snapshot.upload", bytes=nbytes):
+        state = device_put_like(host_state, like_state, shardings)
+        jax.block_until_ready(state)
+    return state
+
+
+def replay(step_at: Callable, batch_fn: Callable, state,
+           from_step: int, to_step: int, *,
+           on_step: Optional[Callable] = None) -> ReplayResult:
     """Replay steps ``from_step`` (the snapshot was taken *before*
-    executing it) up to (but not including) ``to_step``.
+    executing it) up to (but not including) ``to_step``, from ``state``:
+    the snapshot already on the device (``upload``).
 
     step_at(step, state, batch) -> (state, metrics) runs step ``step`` with
     the program the recovered run used for it; batch_fn(step) -> batch.
-    ``shardings`` places the snapshot on a mesh when no ``like_state``
-    reference survives (donated loops).
     """
     assert to_step >= from_step, (from_step, to_step)
-    nbytes = sum(leaf.nbytes
-                 for leaf in jax.tree_util.tree_leaves(snapshot_state))
-    with obs.span("snapshot.upload", bytes=nbytes):
-        state = device_put_like(snapshot_state, like_state, shardings)
-        # timed to the upload's end, not its enqueue
-        jax.block_until_ready(state)
     for s in range(from_step, to_step):
         state, _ = step_at(s, state, batch_fn(s))
         if on_step is not None:

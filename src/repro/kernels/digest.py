@@ -31,8 +31,11 @@ no-fault hot path.  This module replaces that with (DESIGN.md §4.2):
   size; a whole-buffer Pallas pack kernel would need the buffer in VMEM.
 * **host digest path** — ``host_checksum``/``host_tree_checksums`` compute
   the same Fletcher digests in numpy uint32 wraparound arithmetic,
-  bit-identical to the kernel, so micro-snapshot host DMA copies are
-  certified without re-uploading a byte to the device.
+  bit-identical to the kernel: the oracle the device digests are tested
+  against, and the certificate of mesh micro-snapshots (per leaf and per
+  shard of the host copy).  Single-device micro-snapshots are certified
+  on the device instead, through ``digest_subset`` over leaf groups
+  (``core/microcheckpoint.py``).
 * **digest as traceable subcomputation** — ``DigestPlan.digest_fn`` and
   ``check_arm_subcomputation`` return PURE functions whose only host-side
   work (plan lookup, row maps, offsets) happens at build/trace time: the
@@ -728,9 +731,10 @@ def _sharded_check_arm_subcomputation(plan: ShardedDigestPlan,
 
 
 # ---------------------------------------------------------------------------
-# host digest path — certify micro-snapshot host DMA copies without a
-# device re-upload (DESIGN.md §4.2).  Bit-identical to the kernel: numpy
-# uint32 arithmetic wraps mod 2^32 exactly like the int32 device math.
+# host digest path — the oracle of the device digests, and the certificate
+# of mesh micro-snapshots' host copies (DESIGN.md §4.2).  Bit-identical to
+# the kernel: numpy uint32 arithmetic wraps mod 2^32 exactly like the int32
+# device math.
 # ---------------------------------------------------------------------------
 
 def _host_i32(x: np.ndarray) -> np.ndarray:
@@ -790,9 +794,9 @@ def shard_indices(x) -> List[Tuple]:
 def host_shard_checksums(x) -> np.ndarray:
     """(n_shards, 2) host digests of a sharded array, shard order matching
     the sharded digest tables — the single-device uint32 oracle the kernel
-    path is asserted bit-identical against.  (Snapshot certification does
-    NOT route through here: ``core/microcheckpoint.py`` hashes its stored
-    host copy's slices directly via ``host_checksum``, so it never
+    path is asserted bit-identical against.  (Mesh snapshot certification
+    does NOT route through here: ``core/microcheckpoint.py`` hashes its
+    stored host copy's slices directly via ``host_checksum``, so it never
     re-fetches the device.)"""
     host = np.asarray(x)
     return np.stack([host_checksum(host[idx]) for idx in shard_indices(x)])

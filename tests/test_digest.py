@@ -12,6 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.core import microcheckpoint as mc
 from repro.core.detect import ChecksumCanary
 from repro.core.faults import flip_bit
 from repro.core.microcheckpoint import MicroCheckpointer
@@ -182,7 +184,7 @@ def test_micro_snapshot_single_pass_digests_and_cached_memory():
     micro.snapshot(0, tree)
     snap = micro.snapshots[-1]
     # digests certify the stored bytes and match the live state's digests
-    assert micro.verify(snap) == []
+    assert micro.verify(snap, jax.device_put(snap.state)) == []
     live = ops.tree_checksums(tree)
     assert all(np.array_equal(snap.digests[k], live[k]) for k in live)
     # memory accounting cached at snapshot time, no re-materialisation
@@ -401,17 +403,71 @@ def test_host_checksum_matches_oracle_all_dtypes():
                               np.asarray(ref.checksum_ref(a))), a.dtype
 
 
-def test_snapshot_digests_are_host_side_and_bit_exact():
-    """Snapshot certification must never touch the device: zero digest
-    launches/syncs counted, yet the stored digests are bit-identical to
-    the device engine's over the same bytes."""
+@pytest.mark.parametrize("cap", [None, 64 << 10], ids=["whole", "split"])
+def test_snapshot_digests_are_device_side_and_bit_exact(cap, monkeypatch):
+    """A snapshot certifies the live state on the device: the stored
+    digests equal the host oracle over the stored copy and the device
+    engine over the live tree, for one fetch and one launch per leaf
+    group, and a second snapshot retraces nothing.  Under a cap smaller
+    than ``opt/m`` that leaf forms a group alone and still digests
+    bit-identically."""
+    if cap is not None:
+        monkeypatch.setattr(mc, "DIGEST_GROUP_BYTES", cap)
     tree = _tree()
-    live = ops.tree_checksums(tree)           # device digests (warm)
+    live = ops.tree_checksums(tree)
     micro = MicroCheckpointer(interval=1)
+    with obs.span("probe.mark") as mark:
+        pass
     dg.STATS.reset()
     micro.snapshot(0, tree)
+    launches, syncs, _ = dg.STATS.snapshot()
+    digest = [r for r in obs.records()
+              if r.id > mark.id and r.name == "snapshot.digest"]
+    assert len(digest) == 1
+    groups = digest[0].attrs["groups"]
+    assert syncs == 1 and launches == groups
+    assert digest[0].attrs["bytes"] >= 4 * sum(
+        np.size(x) for x in jax.tree_util.tree_leaves(tree))
     snap = micro.snapshots[-1]
-    assert micro.verify(snap) == []
-    launches, syncs, traces = dg.STATS.snapshot()
-    assert launches == 0 and syncs == 0       # pure host DMA path
-    assert all(np.array_equal(snap.digests[k], live[k]) for k in live)
+    assert set(snap.digests) == set(live)
+    for k, leaf in _leaves_by_key(snap.state).items():
+        assert np.array_equal(snap.digests[k], dg.host_checksum(leaf)), k
+        assert np.array_equal(snap.digests[k], live[k]), k
+    plan = dg.plan_for(tree)
+    if cap is None:
+        assert groups == 1
+    else:
+        m = plan.index_of("opt/m")
+        assert plan.specs[m].n_rows * dg.LANES * 4 > cap
+        assert (m,) in mc.digest_groups(plan)[0] and groups > 2
+    dg.STATS.reset()
+    micro.snapshot(1, tree)
+    assert dg.STATS.traces == 0
+
+
+def test_mesh_snapshots_keep_host_digests():
+    """With a mesh context the snapshot stays on the host path: per-leaf
+    and per-shard host digests of the copy, no device digest launched or
+    fetched, and a rotted copy is named by the host re-digest."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.distributed.context import DistContext
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
+    tree = jax.device_put(_tree(), NamedSharding(mesh, P()))
+    micro = MicroCheckpointer(interval=1, ctx=DistContext.for_mesh(mesh))
+    dg.STATS.reset()
+    micro.snapshot(0, tree)
+    assert dg.STATS.snapshot()[:2] == (0, 0)
+    snap = micro.snapshots[-1]
+    leaves = _leaves_by_key(snap.state)
+    assert set(snap.shard_digests) == set(snap.digests) == set(leaves)
+    for k, leaf in leaves.items():
+        assert np.array_equal(snap.digests[k], dg.host_checksum(leaf)), k
+        assert np.array_equal(snap.shard_digests[k][0], snap.digests[k]), k
+    assert micro.verify(snap, tree) == []
+    rotted = np.array(leaves["opt/m"])
+    rotted[5] = -rotted[5]
+    snap.state = dict(snap.state, opt={"m": rotted})
+    assert micro.verify(snap, tree) == ["opt/m"]
+    assert dg.STATS.snapshot()[:2] == (0, 0)

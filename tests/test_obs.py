@@ -175,8 +175,13 @@ def test_train_nests_each_fault_downtime(faulted_run):
             assert _ancestors(r, by_id) == chain, name
             assert r.step == by_id[r.parent].step
     for r in recs:
-        if r.name == "snapshot.upload":
+        if r.name in ("snapshot.upload", "snapshot.verify"):
             assert r.attrs["bytes"] > 0
+        if r.name == "snapshot.verify":
+            # the rung verifies the snapshot as uploaded
+            upload, = [u for u in recs if u.name == "snapshot.upload"
+                       and u.parent == r.parent]
+            assert upload.end <= r.start
         if r.name == "recover.replay":
             assert r.attrs["steps"] == {4: 4, 8: 0}[r.step]
     assert all(_ancestors(r, by_id) == ["snapshot"]

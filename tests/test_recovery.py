@@ -3,12 +3,14 @@ repair -> exact-or-abort verification, on a real (tiny) training loop."""
 
 import dataclasses
 import random
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import (
     ChecksumCanary,
     FaultReport,
@@ -22,12 +24,14 @@ from repro.core import (
     promote,
     sample_plan,
 )
+from repro.core.induction import RecoveryAbort
 from repro.core.recovery_table import (
     RUNG_EQ1,
     RUNG_OPT_IV,
     RUNG_REPLAY,
     RUNG_TRIAGE,
 )
+from repro.kernels import digest as dg
 
 
 def _runtime(tiny_setup, **kw):
@@ -101,6 +105,45 @@ def test_replay_runs_the_given_replay_step(tiny_setup):
     for a, b in zip(jax.tree_util.tree_leaves(fixed),
                     jax.tree_util.tree_leaves(state)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("rot", [False, True], ids=["intact", "rotted"])
+def test_replay_rung_is_exact_or_abort(tiny_setup, rot):
+    """The replay rung uploads its snapshot, then digests it as uploaded:
+    an untouched snapshot replays bit-exactly, and one flipped bit in the
+    host copy aborts the rung naming that leaf.  Either way the attempt
+    records one upload, then one verify."""
+    cfg, state0, step, bfn = tiny_setup
+    rt, micro = _runtime(tiny_setup)
+    state = _advance(step, bfn, state0, 0, 6, micro)
+    snap = micro.latest(before=6)
+    key = next(k for k in snap.digests if k.startswith("params/"))
+
+    def flip(path, leaf):
+        if dg.leaf_key(path) != key:
+            return leaf
+        out = np.array(leaf)
+        out.reshape(-1).view(np.uint8)[1] ^= 0x10
+        return out
+
+    if rot:
+        snap.state = jax.tree_util.tree_map_with_path(flip, snap.state)
+    with obs.span("probe.mark") as mark:
+        pass
+    report = FaultReport(6, "checksum", leaves=[key])
+    if rot:
+        with pytest.raises(RecoveryAbort, match=re.escape(key)):
+            rt._rung_replay(state, report, 6)
+    else:
+        fixed, _ = rt._rung_replay(state, report, 6)
+        for a, b in zip(jax.tree_util.tree_leaves(fixed),
+                        jax.tree_util.tree_leaves(state)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+    recs = [r for r in obs.records() if r.id > mark.id
+            and r.name in ("snapshot.upload", "snapshot.verify")]
+    assert [r.name for r in recs] == ["snapshot.upload", "snapshot.verify"]
+    assert recs[0].end <= recs[1].start
+    assert all(r.attrs["bytes"] > 0 for r in recs)
 
 
 def test_post_recovery_trajectory_is_fault_free(tiny_setup):
