@@ -10,9 +10,32 @@ is a data file of its own, found by name:
   ``serve``);
 * ``limits/<workload>.json``: the limit of each number that decides
   ``correct``, with the readings it was set from;
-* ``metrics/<metric>.py``: the reader of one per-layer metric.
+* ``metrics/<metric>.py``: the reader of one per-layer metric;
+* ``yardsticks/<yardstick>.py``: what the benchmark knows of a model
+  family, named by the configuration's ``"yardstick"`` (``dense`` where
+  the key is absent).
 
-A later cell needs new files and entries only; nothing here names a cell.
+A yardstick is one module that imports nothing of the program and
+provides:
+
+* ``dims(config) -> dict``: the sizes it computes with, read from the
+  configuration's published keys; at least ``layers`` and ``vocab``;
+* ``train_readings(dims, seed, batch, seq, steps, quant=None,
+  rows=None)``: the plain float32 reference's first ``steps`` training
+  steps from the seed's weights and rows, as ``compare.train_numbers``
+  reads them (``quant``: the control's precision; ``rows``: the loss
+  over the first rows only);
+* ``ADAM``: the optimizer settings the reference follows; the program's
+  first gradient is read as ``m / (1 - ADAM["b1"])``;
+* ``program_weights(params) -> {name: ndarray}``: the program's
+  parameter tree under the reference's weight names;
+* ``train_flops(dims, batch, seq)``: the operations of one training
+  step (remat not counted);
+* for a family that serves, ``serve_flops(dims, work)`` and
+  ``serve_gaps(dims, seed, sequences, n_prompt, controls=())``.
+
+A later cell, of a new family too, needs new files and entries only;
+nothing here names a cell or a family.
 """
 
 from __future__ import annotations
@@ -65,11 +88,26 @@ def metrics_for(spec: Dict, name: str, kind: str) -> list:
 def metric_reader(name: str, base: Optional[str] = None):
     """``read(run) -> float | None`` of one per-layer metric."""
     path = os.path.join(base or HERE, "metrics", f"{name}.py")
+    return _module("bench_metric_" + name, path).read
+
+
+def _module(name: str, path: str):
     mod_spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def yardstick(config: Dict, base: Optional[str] = None):
+    """The yardstick module the configuration names, loaded by path."""
+    name = config.get("yardstick", "dense")
+    path = os.path.join(base or HERE, "yardsticks", f"{name}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(
+            f"configuration {config.get('name')!r} names yardstick "
+            f"{name!r}, and there is no {path}")
+    return _module("bench_yardstick_" + name, path)
 
 
 def arch_config(config: Dict):
@@ -81,23 +119,3 @@ def arch_config(config: Dict):
     model = dataclasses.replace(cfg.model, **prog.get("model", {}))
     train = dataclasses.replace(cfg.train, **prog.get("train", {}))
     return dataclasses.replace(cfg, model=model, train=train)
-
-
-def model_dims(config: Dict) -> Dict:
-    """The sizes the yardstick (reference, costs) computes with, read from
-    the published keys of a configuration file."""
-    heads = config["num_attention_heads"]
-    return {
-        "layers": config["num_hidden_layers"],
-        "d_model": config["hidden_size"],
-        "heads": heads,
-        "kv_heads": config["num_key_value_heads"],
-        "head_dim": config.get("head_dim",
-                               config["hidden_size"] // heads),
-        "d_ff": config["intermediate_size"],
-        "vocab": config["vocab_size"],
-        "window": config.get("sliding_window") or 0,
-        "rope_theta": config["rope_theta"],
-        "norm_eps": config["rms_norm_eps"],
-        "tie": config.get("tie_word_embeddings", False),
-    }

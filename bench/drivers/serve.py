@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import compare, cost, reference
+from bench import compare
 from bench import trace as btrace
 
 
@@ -222,7 +222,7 @@ def run(job, controls=()) -> Dict:
         out["trace"] = seam.tracer.read(
             [ent[0] for ent in E._EXEC_CACHE.values()])
         out["counters"]["traced_s"] = seam.span
-        out["counters"]["traced_flops"] = cost.serve_flops(
+        out["counters"]["traced_flops"] = job.yardstick.serve_flops(
             job.dims, seam.work)
         out["counters"]["traced_steps"] = seam.n - seam.start
 
@@ -243,8 +243,8 @@ def run(job, controls=()) -> Dict:
     del eng, rep, seam, host_spans
     kdigest.clear_plan_cache()
     gc.collect()
-    gaps = reference.serve_gaps(job.dims, job.seed, seqs, n_prompt,
-                                controls=controls)
+    gaps = job.yardstick.serve_gaps(job.dims, job.seed, seqs, n_prompt,
+                                    controls=controls)
     checks.append(("logit_gap", compare.widest(g["served"] for g in gaps),
                    job.limits["logit_gap"]["limit"]))
     out["checks"] = checks

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import compare, reference
+from bench import compare
 from bench import digest as hdigest
 from bench import trace as btrace
 
@@ -53,23 +53,6 @@ def host(tree):
     donated buffer would veto its donation."""
     return jax.tree_util.tree_map(
         lambda x: np.asarray(jnp.array(x, copy=True)), tree)
-
-
-def program_weights(params) -> Dict[str, np.ndarray]:
-    """The program's parameter tree as the reference's weight names."""
-    blk = params["groups"][0][0]
-    out = {"embed": params["embed"]["table"],
-           "final_norm": params["final_norm"]["scale"],
-           "head": params["head"]["w"]}
-    parts = {"ln1": blk["ln1"]["scale"], "ln2": blk["ln2"]["scale"],
-             "wq": blk["attn"]["wq"]["w"], "wk": blk["attn"]["wk"]["w"],
-             "wv": blk["attn"]["wv"]["w"], "wo": blk["attn"]["wo"]["w"],
-             "gate": blk["ffn"]["gate"]["w"], "up": blk["ffn"]["up"]["w"],
-             "down": blk["ffn"]["down"]["w"]}
-    for name, x in parts.items():
-        for i in range(x.shape[0]):
-            out[f"layers.{i}.{name}"] = x[i]
-    return {k: np.asarray(v) for k, v in out.items()}
 
 
 class Seams:
@@ -310,11 +293,12 @@ def run(job) -> Dict:
 
     # -- the model step against the reference -------------------------------
     caps = seams.captures
+    yard = job.yardstick
     prog = {"losses": [float(seams.losses[s]) for s in range(3)],
-            "params0": program_weights(caps[0]),
-            "params": program_weights(caps[3]),
-            "grad0": {k: v / (1 - reference.ADAM["b1"]) for k, v in
-                      program_weights(caps[1]).items()}}
+            "params0": yard.program_weights(caps[0]),
+            "params": yard.program_weights(caps[3]),
+            "grad0": {k: v / (1 - yard.ADAM["b1"]) for k, v in
+                      yard.program_weights(caps[1]).items()}}
     seams.captures = {}
     refr = job.reference_train(steps=3)
     nums = compare.train_numbers(prog, refr)

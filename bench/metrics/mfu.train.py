@@ -1,8 +1,7 @@
 """Model FLOP/s utilisation of training over the traced steps: the
-operations the forward and backward passes require (remat recompute not
-counted) times steps, over the traced span and the chips' bf16 peak."""
-
-from bench import cost
+operations the forward and backward passes require, as the
+configuration's yardstick counts them (remat recompute not counted),
+times steps, over the traced span and the chips' bf16 peak."""
 
 
 def read(run):
@@ -10,7 +9,7 @@ def read(run):
     if not c.get("traced_s"):
         return None
     t = run["traffic"]
-    flops = cost.train_flops(run["dims"], t["global_batch"], t["seq_len"]) \
-        * c["traced_steps"]
+    flops = run["yardstick"].train_flops(
+        run["dims"], t["global_batch"], t["seq_len"]) * c["traced_steps"]
     return 100.0 * flops / c["traced_s"] / (
         run["chips"] * run["peak"]["bf16_flops_per_s"])

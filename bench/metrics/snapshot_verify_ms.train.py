@@ -1,5 +1,6 @@
-"""Time the replay rung spends re-digesting its snapshot on the host, per
-fault recovered in the window (the program's ``snapshot.verify`` span)."""
+"""Time the replay rung spends digesting the uploaded snapshot on the
+device against its stored digests, per fault recovered in the window
+(the program's ``snapshot.verify`` span)."""
 
 from bench import spans
 

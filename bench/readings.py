@@ -32,28 +32,26 @@ ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-from bench import cells, compare, reference  # noqa: E402
+from bench import cells, compare  # noqa: E402
 from bench import run as bench_run  # noqa: E402
 
 CONTROL = "float8_e4m3fn"
 
 
-def train(job, seed):
-    t = job.traffic
-    args = (job.dims, seed, t["global_batch"], t["seq_len"])
-    ref = reference.train_readings(*args)
+def train(job):
+    ref = job.reference_train()
     out = {}
     for name, kw in (("control", {"quant": CONTROL}),
-                     ("half_batch", {"rows": t["global_batch"] // 2})):
-        got = reference.train_readings(*args, **kw)
+                     ("half_batch",
+                      {"rows": job.traffic["global_batch"] // 2})):
+        got = job.reference_train(**kw)
         out[name] = {k: v for k, (v, _) in
                      compare.train_numbers(got, ref).items()}
     return out
 
 
-def serve(job, seed):
+def serve(job):
     from bench.drivers import serve as driver
-    job.seed = seed
     rec = driver.run(job, controls=(CONTROL,))
     checks = {n: v for n, v, _ in rec["checks"]}
     return {"program_logit_gap": checks["logit_gap"],
@@ -78,7 +76,7 @@ def main(argv=None) -> int:
         for seed in args.seeds:
             job = bench_run.Job(spec, args.cell, seed, args.seconds, 0,
                                 scratch)
-            out = (train if args.kind == "train" else serve)(job, seed)
+            out = (train if args.kind == "train" else serve)(job)
             print(json.dumps({"cell": args.cell, "seed": seed,
                               "device": device, **out}), flush=True)
     return 0

@@ -3,8 +3,9 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
 
-The cell, its configuration, its traffic, its limits and its per-layer
-metric readers are found by name under ``bench/`` (see ``cells.py``).
+The cell, its configuration and the yardstick it names, its traffic, its
+limits and its per-layer metric readers are found by name under
+``bench/`` (see ``cells.py``).
 The traffic's ``kind`` names the general driver in ``bench/drivers/``.
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of part
@@ -73,17 +74,18 @@ class Job:
         self.config = cells.load_config(self.workload["config"], base)
         self.traffic = cells.load_traffic(self.workload["traffic"], base)
         self.limits = cells.load_limits(name, base)
-        self.dims = cells.model_dims(self.config)
+        self.yardstick = cells.yardstick(self.config, base)
+        self.dims = self.yardstick.dims(self.config)
         self.arch = cells.arch_config(self.config)
 
     memory_peak = staticmethod(memory_peak)
 
-    def reference_train(self, steps: int, **kw):
-        from bench import reference
+    def reference_train(self, steps: int = 3, **kw):
+        """The yardstick's reference over the traffic's first steps."""
         t = self.traffic
-        return reference.train_readings(self.dims, self.seed,
-                                        t["global_batch"], t["seq_len"],
-                                        steps=steps, **kw)
+        return self.yardstick.train_readings(
+            self.dims, self.seed, t["global_batch"], t["seq_len"],
+            steps=steps, **kw)
 
 
 def enable_cache() -> str:
@@ -130,8 +132,8 @@ def result_line(job, run, setup_s, device) -> dict:
         tr = run.get("trace") or {}
         dev["busy_s"] = tr.get("busy_s", 0.0)
         dev["window_s"] = tr.get("window_s", 0.0)
-        run = dict(run, dims=job.dims, traffic=job.traffic,
-                   chips=job.workload["chips"],
+        run = dict(run, yardstick=job.yardstick, dims=job.dims,
+                   traffic=job.traffic, chips=job.workload["chips"],
                    peak=peaks(device["kind"], job.base))
         metrics = per_layer(job.spec, job.name, run, job.base)
     else:

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import shutil
 
 import jax
@@ -91,7 +92,9 @@ def smoke_root(tmp_path_factory):
     b = root / "bench"
     for sub in ("configs", "traffic", "limits"):
         (b / sub).mkdir(parents=True)
-    shutil.copytree(os.path.join(cells.HERE, "metrics"), b / "metrics")
+    for sub in ("metrics", "yardsticks"):
+        shutil.copytree(os.path.join(cells.HERE, sub), b / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     # the CPU has no entry in the peak table; the rehearsal's own copy
     # gets a placeholder so that the per-layer readers run (their values
     # here mean nothing)
@@ -136,6 +139,81 @@ def rehearse(root, name, capsys, trace=0, seed=2 ** 31 + 11):
     assert rc == 0
     out = capsys.readouterr()
     return json.loads(out.out.strip().splitlines()[-1]), out.err
+
+
+#: a yardstick that only a temporary root holds: the dense one's source,
+#: with a log of the calls the harness makes into it
+LOGGED_YARDSTICK = """
+
+CALLS = []
+
+
+def _logged(fn):
+    def call(*a, **kw):
+        CALLS.append(fn.__name__)
+        return fn(*a, **kw)
+    return call
+
+
+dims, train_readings, program_weights, train_flops = map(
+    _logged, (dims, train_readings, program_weights, train_flops))
+"""
+
+
+@pytest.fixture(scope="module")
+def named_root(smoke_root, tmp_path_factory):
+    """The smoke root with its configuration naming ``smoke_dense``, a
+    yardstick no other root has, and one naming a yardstick with no
+    file; the dense yardstick is taken out of this root."""
+    root = tmp_path_factory.mktemp("named_root")
+    shutil.copytree(smoke_root, root, dirs_exist_ok=True)
+    ys = root / "bench" / "yardsticks"
+    dense = ys / "dense.py"
+    (ys / "smoke_dense.py").write_text(dense.read_text() + LOGGED_YARDSTICK)
+    dense.unlink()
+    configs = root / "bench" / "configs"
+    for name, yard in (("danube-smoke", "smoke_dense"),
+                       ("danube-absent", "absent")):
+        (configs / f"{name}.json").write_text(json.dumps(
+            dict(SMOKE_CONFIG, name=name, yardstick=yard)))
+    spec = cells.load_benchmark(str(root))
+    smoke = cells.workload(spec, "smoke_train")
+    spec["workloads"].append(dict(smoke, name="smoke_absent",
+                                  config="danube-absent"))
+    spec["configs"].append(dict(spec["configs"][0], name="danube-absent",
+                                file="bench/configs/danube-absent.json"))
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    shutil.copy(root / "bench" / "limits" / "smoke_train.json",
+                root / "bench" / "limits" / "smoke_absent.json")
+    return str(root)
+
+
+def test_named_yardstick_runs_from_its_root(named_root, capsys,
+                                            monkeypatch):
+    """A configuration that names a yardstick found only in the run's
+    root is run through a training cell by that yardstick alone."""
+    loaded = []
+    real = cells.yardstick
+
+    def keep(config, base=None):
+        loaded.append(real(config, base))
+        return loaded[-1]
+    monkeypatch.setattr(cells, "yardstick", keep)
+    line, _ = rehearse(named_root, "smoke_train", capsys, trace=1)
+    assert line["correct"], line["checks"]
+    assert "mfu.train" in line["metrics"]
+    (mod,) = loaded
+    assert mod.__file__ == os.path.join(named_root, "bench", "yardsticks",
+                                        "smoke_dense.py")
+    assert {"dims", "train_readings", "program_weights",
+            "train_flops"} <= set(mod.CALLS)
+
+
+def test_missing_yardstick_fails_naming_its_path(named_root, capsys):
+    path = os.path.join(named_root, "bench", "yardsticks", "absent.py")
+    with pytest.raises(FileNotFoundError, match=re.escape(path)):
+        rehearse(named_root, "smoke_absent", capsys)
+    assert capsys.readouterr().out == ""
 
 
 def test_no_chip_no_run(capsys):

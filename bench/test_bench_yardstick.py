@@ -1,6 +1,6 @@
-"""The yardstick checked on the CPU: the reference against the program at
-smoke widths, the controls against the limits, the traffic generator,
-the operation and byte counts, and the trace reduction."""
+"""The dense yardstick checked on the CPU: the reference against the
+program at smoke widths, the controls against the limits, the traffic
+generator, the operation and byte counts, and the trace reduction."""
 
 from __future__ import annotations
 
@@ -12,15 +12,16 @@ import jax
 import numpy as np
 import pytest
 
-from bench import cells, compare, cost, reference
+from bench import cells, compare
+from bench import run as bench_run
 from bench import trace as btrace
 from bench.drivers import serve as serve_driver
-from bench.drivers import train as train_driver
 from bench.test_bench_rehearsal import (SERVE, SERVE_LIMITS, SMOKE_CONFIG,
-                                        TRAIN_LIMITS)
+                                        TRAIN_LIMITS, smoke_root)  # noqa: F401
+from bench.yardsticks import dense
 
 SEED = 2 ** 31 + 5
-DIMS = cells.model_dims(SMOKE_CONFIG)
+DIMS = dense.dims(SMOKE_CONFIG)
 
 
 @pytest.fixture(scope="module")
@@ -31,8 +32,8 @@ def arch():
 def test_reference_weights_are_the_programs(arch):
     from repro.models.registry import get_model
     prog = get_model(arch.model).init(arch.model, jax.random.PRNGKey(SEED))
-    ours = reference.flat(reference.init_params(DIMS, SEED))
-    theirs = train_driver.program_weights(prog)
+    ours = dense.flat(dense.init_params(DIMS, SEED))
+    theirs = dense.program_weights(prog)
     assert sorted(ours) == sorted(theirs)
     for k in ours:
         a, b = np.asarray(ours[k]), theirs[k]
@@ -44,7 +45,7 @@ def test_reference_rows_are_the_pipelines():
     from repro.data.pipeline import TokenPipeline
     pipe = TokenPipeline(DIMS["vocab"], 32, 8, seed=SEED)
     for step in (0, 3):
-        ours = reference.batch_at(SEED, step, 8, 32, DIMS["vocab"])
+        ours = dense.batch_at(SEED, step, 8, 32, DIMS["vocab"])
         theirs = pipe.batch_at(step)
         for k in ("tokens", "targets"):
             assert np.array_equal(ours[k], theirs[k])
@@ -57,21 +58,41 @@ def test_reference_loss_matches_program_in_float32(arch):
     m32 = dataclasses.replace(arch.model, compute_dtype="float32")
     model = get_model(m32)
     params = model.init(m32, jax.random.PRNGKey(SEED))
-    b = reference.batch_at(SEED, 0, 8, 32, DIMS["vocab"])
+    b = dense.batch_at(SEED, 0, 8, 32, DIMS["vocab"])
     with jax.default_matmul_precision("highest"):
         prog, _ = model.train_loss(params, m32, b, remat=False)
-    ref = reference.init_params(DIMS, SEED)
-    ours = np.mean([float(reference.seq_loss(ref, b["tokens"][i],
+    ref = dense.init_params(DIMS, SEED)
+    ours = np.mean([float(dense.seq_loss(ref, b["tokens"][i],
                                              b["targets"][i], DIMS))
                     for i in range(8)])
     assert abs(float(prog) - ours) / ours < 1e-5
 
 
+def test_job_yardstick_is_the_module(smoke_root):  # noqa: F811
+    """The harness's yardstick, loaded by path for a cell's Job, reads
+    what the module reads when imported: the same losses, gradient norms
+    and operation counts."""
+    spec = cells.load_benchmark(smoke_root)
+    job = bench_run.Job(spec, "smoke_train", SEED, 1.0, 0, "",
+                        os.path.join(smoke_root, "bench"))
+    assert job.yardstick is not dense
+    assert job.dims == DIMS
+    got = job.reference_train()
+    want = dense.train_readings(DIMS, SEED, 8, 32)
+    assert got["losses"] == want["losses"]
+    assert sorted(got["grad0"]) == sorted(want["grad0"])
+    for k in want["grad0"]:
+        assert compare.norm(got["grad0"][k]) == compare.norm(
+            want["grad0"][k]), k
+    assert job.yardstick.train_flops(DIMS, 8, 32) == \
+        dense.train_flops(DIMS, 8, 32)
+
+
 def test_training_control_fails_a_limit():
     """The reference computed with float8 operands, in the program's
     place, reads over the smoke size's limit on at least one number."""
-    ref = reference.train_readings(DIMS, SEED, 8, 32)
-    ctl = reference.train_readings(DIMS, SEED, 8, 32, quant="float8_e4m3fn")
+    ref = dense.train_readings(DIMS, SEED, 8, 32)
+    ctl = dense.train_readings(DIMS, SEED, 8, 32, quant="float8_e4m3fn")
     nums = compare.train_numbers(ctl, ref)
     assert any(v > TRAIN_LIMITS[k] for k, (v, _) in nums.items()), nums
     same = compare.train_numbers(ref, ref)
@@ -82,7 +103,7 @@ def test_serving_control_fails_the_limit():
     rng = np.random.default_rng(0)
     seqs = [rng.integers(0, DIMS["vocab"], 40).astype(np.int32)
             for _ in range(3)]
-    gaps = reference.serve_gaps(DIMS, SEED, seqs, [16, 16, 16],
+    gaps = dense.serve_gaps(DIMS, SEED, seqs, [16, 16, 16],
                                 controls=("float8_e4m3fn",))
     assert compare.widest(g["float8_e4m3fn"] for g in gaps) \
         > SERVE_LIMITS["logit_gap"]
@@ -102,15 +123,15 @@ def test_same_sizes_for_every_seed():
 
 
 def test_operation_counts():
-    d = cells.model_dims(cells.load_config("h2o-danube-1.8b-2l"))
+    d = dense.dims(cells.load_config("h2o-danube-1.8b-2l"))
     per_layer = 2 * 2560 * 2560 + 2 * 2560 * 640 + 3 * 2560 * 6912
-    assert cost.matmul_params(d) == 2 * per_layer + 2560 * 8000
-    assert cost.attended_pairs(4, 0) == 10
-    assert cost.attended_pairs(6, 4) == 10 + 2 * 4
-    f = cost.train_flops(d, 8, 4096)
-    assert math.isclose(f / (8 * 4096), 6 * cost.matmul_params(d)
+    assert dense.matmul_params(d) == 2 * per_layer + 2560 * 8000
+    assert dense.attended_pairs(4, 0) == 10
+    assert dense.attended_pairs(6, 4) == 10 + 2 * 4
+    f = dense.train_flops(d, 8, 4096)
+    assert math.isclose(f / (8 * 4096), 6 * dense.matmul_params(d)
                         + 3 * 4 * 32 * 80 * 2 * 4097 / 2, rel_tol=1e-9)
-    assert cost.prefill_flops(d, 1) == 2 * cost.matmul_params(d) \
+    assert dense.prefill_flops(d, 1) == 2 * dense.matmul_params(d) \
         + 4 * 32 * 80 * 2
 
 

@@ -1,4 +1,6 @@
-"""Plain float32 reference of the dense decoder the benchmark runs.
+"""Yardstick of the dense decoder (Mistral-style): its sizes, its plain
+float32 reference, the program's weights under the reference's names,
+and the operations its work needs.  The contract is in ``cells.py``.
 
 Imports nothing of the program and takes nothing it made.  Weights and
 training rows are made here from the seed, by the scheme the model's
@@ -17,6 +19,11 @@ Parameters are held as the configuration stores them (bfloat16) and
 upcast for the arithmetic; moments are float32.  Attention runs over
 blocks of queries so that one 4096-token sequence never holds all its
 scores at once.
+
+Model operations count what the forward (and, for training, backward)
+pass requires: two operations per multiply-add of every weight matrix
+and of every attended (query, key) pair; recomputation under remat is
+not counted, and neither are the masked-out scores the program computes.
 """
 
 from __future__ import annotations
@@ -31,6 +38,26 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 STORE = jnp.bfloat16
 Q_BLOCK = 512
+
+
+def dims(config: Dict) -> Dict:
+    """The sizes the yardstick computes with, read from the published
+    keys of a configuration file."""
+    heads = config["num_attention_heads"]
+    return {
+        "layers": config["num_hidden_layers"],
+        "d_model": config["hidden_size"],
+        "heads": heads,
+        "kv_heads": config["num_key_value_heads"],
+        "head_dim": config.get("head_dim",
+                               config["hidden_size"] // heads),
+        "d_ff": config["intermediate_size"],
+        "vocab": config["vocab_size"],
+        "window": config.get("sliding_window") or 0,
+        "rope_theta": config["rope_theta"],
+        "norm_eps": config["rms_norm_eps"],
+        "tie": config.get("tie_word_embeddings", False),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -363,3 +390,86 @@ def serve_gaps(dims: Dict, seed: int, sequences: List[np.ndarray],
                 ref, top[:, None], axis=-1)[:, 0])
         out.append(res)
     return out
+
+
+# ---------------------------------------------------------------------------
+# the program's weights under the reference's names
+# ---------------------------------------------------------------------------
+
+def program_weights(params) -> Dict[str, np.ndarray]:
+    """The program's parameter tree as the reference's weight names."""
+    blk = params["groups"][0][0]
+    out = {"embed": params["embed"]["table"],
+           "final_norm": params["final_norm"]["scale"],
+           "head": params["head"]["w"]}
+    parts = {"ln1": blk["ln1"]["scale"], "ln2": blk["ln2"]["scale"],
+             "wq": blk["attn"]["wq"]["w"], "wk": blk["attn"]["wk"]["w"],
+             "wv": blk["attn"]["wv"]["w"], "wo": blk["attn"]["wo"]["w"],
+             "gate": blk["ffn"]["gate"]["w"], "up": blk["ffn"]["up"]["w"],
+             "down": blk["ffn"]["down"]["w"]}
+    for name, x in parts.items():
+        for i in range(x.shape[0]):
+            out[f"layers.{i}.{name}"] = x[i]
+    return {k: np.asarray(v) for k, v in out.items()}
+
+
+# ---------------------------------------------------------------------------
+# operations the work needs, from shapes alone
+# ---------------------------------------------------------------------------
+
+def layer_matmul_params(dims: Dict) -> int:
+    d, h, kv, hd, ff = (dims["d_model"], dims["heads"], dims["kv_heads"],
+                        dims["head_dim"], dims["d_ff"])
+    return 2 * d * h * hd + 2 * d * kv * hd + 3 * d * ff
+
+
+def matmul_params(dims: Dict) -> int:
+    """Weights that a token multiplies: the layers and the head (the
+    embedding is a lookup)."""
+    return dims["layers"] * layer_matmul_params(dims) \
+        + dims["d_model"] * dims["vocab"]
+
+
+def attended_pairs(seq: int, window: int) -> int:
+    """(query, key) pairs of causal attention over ``seq`` positions,
+    keys at most ``window`` back."""
+    w = window or seq
+    if seq <= w:
+        return seq * (seq + 1) // 2
+    return w * (w + 1) // 2 + (seq - w) * w
+
+
+def attn_flops_per_pair(dims: Dict) -> int:
+    """Scores and the weighted sum: 2 x 2 x head_dim per head."""
+    return 4 * dims["heads"] * dims["head_dim"]
+
+
+def train_flops(dims: Dict, batch: int, seq: int) -> float:
+    """One training step of ``batch`` sequences: forward and backward
+    (three times the forward)."""
+    fwd = 2 * matmul_params(dims) * batch * seq \
+        + attn_flops_per_pair(dims) * dims["layers"] * batch \
+        * attended_pairs(seq, dims["window"])
+    return 3.0 * fwd
+
+
+def prefill_flops(dims: Dict, prompt: int) -> float:
+    """A prompt's prefill: every position through the layers, the head
+    for the last position only."""
+    head = dims["d_model"] * dims["vocab"]
+    return 2.0 * (matmul_params(dims) - head) * prompt + 2.0 * head \
+        + attn_flops_per_pair(dims) * dims["layers"] \
+        * attended_pairs(prompt, dims["window"])
+
+
+def decode_flops(dims: Dict, tokens: int, context: int) -> float:
+    """``tokens`` decoded tokens whose attended contexts add up to
+    ``context`` positions."""
+    return 2.0 * matmul_params(dims) * tokens \
+        + attn_flops_per_pair(dims) * dims["layers"] * context
+
+
+def serve_flops(dims: Dict, work: Dict) -> float:
+    return decode_flops(dims, work["decode_tokens"],
+                        work["decode_context"]) \
+        + sum(prefill_flops(dims, p) for p in work["prefill"])
