@@ -453,23 +453,23 @@ def sharded_steady_state(campaign: Campaign, steps: int = 10,
 
     # --- byte accounting vs the cost model ------------------------------
     # the DESIGN §5 model: every device packs its LOCAL shard of each
-    # leaf row-aligned (512 B rows, 128 KiB tile granularity per pass),
-    # and the global pass is exactly n_shards local passes.  Recompute
-    # the prediction independently from the shard shapes and require
-    # exact agreement with the plan's accounting.
-    LANES, TILE_ROWS = 128, 256
-    local_rows = sum(
+    # leaf tile-aligned (128 KiB tiles), and the global pass is exactly
+    # n_shards local passes.  Recompute the prediction independently
+    # from the shard shapes and require exact agreement with the plan's
+    # accounting.
+    TILE = 256 * 128
+    local_tiles = sum(
         max(1, -(-int(np.prod(x.sharding.shard_shape(jax.numpy.shape(x)),
-                               dtype=np.int64) or 1) // LANES))
+                               dtype=np.int64) or 1) // TILE))
         for x in jax.tree_util.tree_leaves(state))
-    expected_local = -(-local_rows // TILE_ROWS) * TILE_ROWS * LANES * 4
+    expected_local = local_tiles * TILE * 4
     assert plan.local_bytes_per_pass == expected_local, (
         plan.local_bytes_per_pass, expected_local)
     assert plan.bytes_per_pass == plan.local_bytes_per_pass * plan.n_shards
     digested_per_step = 2 * plan.bytes_per_pass / n_slices
-    # alignment overhead (≤512 B/leaf/shard + tile tail) — reported; it
-    # is a fixed byte count, so it amortises to ~1x on production states
-    # and only looks large on this CPU smoke state split D ways
+    # alignment overhead (< 128 KiB/leaf/shard) — reported; it is a fixed
+    # byte count, so it amortises to ~1x on production states and only
+    # looks large on this CPU smoke state split D ways
     pack_ratio = plan.bytes_per_pass / state_bytes
 
     return {

@@ -34,7 +34,9 @@ Layout: the per-leaf parity blocks are concatenated into ONE int32 buffer —
 
   * off-mesh: tile-shaped ``(nt, TILE_ROWS, LANES)`` so the hot-path update
     is a single Pallas launch (``kernels.parity.xor_update_tiles``, parity
-    aliased in place);
+    aliased in place).  Each leaf's ``old ^ new`` delta is folded over its
+    D blocks before it is laid out, so the kernel's input is one
+    parity-sized buffer, written in place, not the D-row stream;
   * on a mesh: ``(D, Crow)`` sharded ``P(axis_names, None)`` like the digest
     packing buffers — each device holds 1/D of the parity (total memory
     overhead = state_bytes/D).
@@ -329,11 +331,19 @@ class ParityPlan:
                 mat, NamedSharding(self.mesh, P(self.axis_names, None)))
         return mat
 
-    def _to_tiles(self, mat: jnp.ndarray) -> jnp.ndarray:
-        """(D, stream_len) -> (D, nt, TILE_ROWS, LANES) (off-mesh)."""
-        pad = self.n_tiles * TILE - self.stream_len
-        return jnp.pad(mat, ((0, 0), (0, pad))).reshape(
-            self.n_shards, self.n_tiles, TILE_ROWS, LANES)
+    def _tiles(self, blocks: Sequence) -> jnp.ndarray:
+        """(r, nt, TILE_ROWS, LANES) off-mesh kernel input from each key's
+        (r, block_len) rows: every row written in place into one flat
+        zeroed buffer, whose tile reshape is free, so the stream, its tail
+        pad and its tile layout are one temporary, not three."""
+        r = blocks[0].shape[0]
+        n = self.n_tiles * TILE
+        flat = jnp.zeros((r * n,), jnp.int32)
+        for k, blk in zip(self.keys, blocks):
+            for d in range(r):
+                flat = jax.lax.dynamic_update_slice(
+                    flat, blk[d], (d * n + self.offsets[k],))
+        return flat.reshape(r, self.n_tiles, TILE_ROWS, LANES)
 
     def _fold_rows(self, mat: jnp.ndarray) -> jnp.ndarray:
         """XOR-reduce the shard axis and lay the fold out as the mesh
@@ -371,10 +381,11 @@ class ParityPlan:
                 z = jax.lax.with_sharding_constraint(
                     z, NamedSharding(self.mesh, self.buffer_spec))
             return z
-        mat = self.stream_mat(leaves)
         if self.mesh is not None:
-            return self._fold_rows(mat)
-        return pk.xor_fold_tiles(self._to_tiles(mat))
+            return self._fold_rows(self.stream_mat(leaves))
+        return pk.xor_fold_tiles(self._tiles(
+            [self._leaf_blocks(k, leaf)
+             for k, leaf in zip(self.keys, leaves)]))
 
     def update_leaves(self, parity, old_leaves: Sequence,
                       new_leaves: Sequence, fault) -> jnp.ndarray:
@@ -385,11 +396,20 @@ class ParityPlan:
         donated parity buffer is consumed exactly once (alias-safe)."""
         if not self.keys:
             return parity
-        delta = self.stream_mat(old_leaves) ^ self.stream_mat(new_leaves)
-        delta = jnp.where(fault, jnp.int32(0), delta)
         if self.mesh is not None:
+            delta = self.stream_mat(old_leaves) ^ self.stream_mat(new_leaves)
+            delta = jnp.where(fault, jnp.int32(0), delta)
             return parity ^ self._fold_rows(delta)
-        return pk.xor_update_tiles(self._to_tiles(delta), parity)
+        # off-mesh, fold each leaf's delta over its D blocks before laying
+        # it out: the kernel's input is one parity's size, not D of them
+        folded = []
+        for k, a, b in zip(self.keys, old_leaves, new_leaves):
+            delta = self._leaf_blocks(k, a) ^ self._leaf_blocks(k, b)
+            fold = delta[0]
+            for d in range(1, delta.shape[0]):
+                fold = fold ^ delta[d]
+            folded.append(jnp.where(fault, jnp.int32(0), fold)[None])
+        return pk.xor_update_tiles(self._tiles(folded), parity)
 
     # -- fault path: reconstruction ---------------------------------------
 

@@ -8,14 +8,15 @@ no-fault hot path.  This module replaces that with (DESIGN.md §4.2):
 
 * **DigestPlan** — computed once per state *structure* (treedef + leaf
   shapes/dtypes) and cached: a flat int32 packing layout where every leaf
-  occupies a private, row-aligned (128-element / 512 B) range of a single
-  buffer — dense enough that a state with hundreds of small leaves packs
-  to ~its own size, not 128 KiB per leaf — plus the row→leaf segment map
-  and per-row offset table the combine needs.
+  occupies a private, TILE-aligned (32768-element / 128 KiB) range of a
+  single buffer, so every tile belongs to exactly one leaf.  Alignment
+  costs at most one tile per leaf (11 MiB for the 88 leaves of a K=1
+  check-and-arm union) and buys a combine with no per-row tables.
 * **one Pallas launch** per digest: all selected leaves are packed into
   one (nt, TILE_ROWS, LANES) buffer and digested by a single
-  ``row_checksums`` pallas_call; per-leaf digests are exact segment sums
-  of the per-row partials (int32 wraparound arithmetic, so the result is
+  ``tile_checksums`` pallas_call, whose lane-dense (2, nt, LANES) output
+  ``checksum.combine`` folds over each leaf's static tile range in one
+  masked reduction (int32 wraparound arithmetic, so the result is
   bit-identical to per-leaf ``ops.checksum``).
 * **device-side comparison** — consumers keep an on-device reference
   digest table (n_leaves, 2) and compare tables on device, fetching one
@@ -38,7 +39,7 @@ no-fault hot path.  This module replaces that with (DESIGN.md §4.2):
   (``core/microcheckpoint.py``).
 * **digest as traceable subcomputation** — ``DigestPlan.digest_fn`` and
   ``check_arm_subcomputation`` return PURE functions whose only host-side
-  work (plan lookup, row maps, offsets) happens at build/trace time: the
+  work (plan lookup, tile ranges) happens at build/trace time: the
   traced path carries no dict lookups, so callers can embed a digest
   inside their own jitted program.  ``core/fused_step.py`` uses this to
   run the canary check+arm INSIDE the jitted (donated) training step.
@@ -65,7 +66,7 @@ table in code form; D=1 off-mesh):
 
 Mesh sharding (``ShardedDigestPlan``/``sharded_plan_for``; DESIGN.md §5)
 changes the *placement* of the work, not the contract: under shard_map
-every device packs and digests only its addressable shard rows against
+every device packs and digests only its addressable shard blocks against
 its own slice of the sharded (n_shards, L, 2) reference tables, and the
 single scalar the host fetches is the all-reduced any(fault) flag — the
 only cross-device communication on the no-fault path.  Shard digests are
@@ -74,9 +75,10 @@ each shard's bytes (``host_shard_checksums``).
 
 Instrumentation: ``STATS`` counts launches (one per digest invocation —
 each digest is one jitted program: the in-place pack + one
-``row_checksums`` pallas_call, counted as a single fused launch; the in-step fused mode counts its one
-combined step+digest dispatch), host syncs (every device→host fetch in
-this module and in the canary goes through ``fetch``), and traces
+``tile_checksums`` pallas_call, counted as a single fused launch; the
+in-step fused mode counts its one combined step+digest dispatch), host
+syncs (every device→host fetch in this module and in the canary goes
+through ``fetch``), and traces
 (incremented inside traced bodies, so a plan-cache hit provably does not
 retrace).  The host digest path touches no device and counts nothing.
 """
@@ -89,7 +91,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ops import segment_sum
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels import checksum as _ck
@@ -97,6 +98,7 @@ from repro.kernels import ref as _ref
 
 LANES = _ck.LANES
 TILE_ROWS = _ck.TILE_ROWS
+TILE = _ck.TILE
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +149,11 @@ class LeafSpec:
     key: str
     index: int          # position in the plan's canonical (sorted-key) order
     size: int           # int32 elements (== element count; to_i32 is 1:1)
-    n_rows: int         # row-aligned footprint: max(1, ceil(size/LANES))
+    n_rows: int         # tile-aligned: TILE_ROWS·max(1, ceil(size/TILE))
+
+    @property
+    def n_tiles(self) -> int:
+        return self.n_rows // TILE_ROWS
 
 
 class DigestPlan:
@@ -164,7 +170,7 @@ class DigestPlan:
         self.keys = keys                       # sorted
         self.specs = tuple(
             LeafSpec(key=k, index=i, size=s,
-                     n_rows=max(1, -(-s // LANES)))
+                     n_rows=TILE_ROWS * max(1, -(-s // TILE)))
             for i, (k, s) in enumerate(zip(keys, sizes)))
         self.n_leaves = len(keys)
         self.n_rows = sum(sp.n_rows for sp in self.specs)
@@ -222,43 +228,23 @@ class DigestPlan:
         return fn
 
     def _build_digest_fn(self, idx: Tuple[int, ...]):
-        specs = [self.specs[i] for i in idx]
-        n_rows = sum(sp.n_rows for sp in specs)
-        padded_rows = -(-n_rows // TILE_ROWS) * TILE_ROWS
-        nt = padded_rows // TILE_ROWS
-        # row→leaf segment map; pad/fill rows stay all-zero for the life of
-        # the persistent buffer so they contribute nothing to whichever
-        # segment they land in (use 0)
-        seg_ids = np.zeros(padded_rows, np.int32)
-        offsets = np.zeros(padded_rows, np.int32)
-        starts: List[int] = []     # element offset of each leaf in the buf
-        r = 0
-        for j, sp in enumerate(specs):
-            seg_ids[r:r + sp.n_rows] = j
-            # each row's element offset within its leaf, for the exact
-            # Fletcher combine: Σ(off+j)·x = off·Σx + Σj·x (mod 2^32)
-            offsets[r:r + sp.n_rows] = \
-                np.arange(sp.n_rows, dtype=np.int32) * np.int32(LANES)
-            starts.append(r * LANES)
-            r += sp.n_rows
-        n_seg = len(specs)
+        # leaf j owns tiles [first[j], stop[j]) of the buffer; fill tiles
+        # and each leaf's tail stay all-zero for the life of the
+        # persistent buffer, so they add nothing to any digest
+        bounds = np.cumsum([0] + [self.specs[i].n_tiles for i in idx])
+        first, stop = bounds[:-1], bounds[1:]
+        nt = int(bounds[-1])
 
         def digest(buf, leaves):
             STATS.traces += 1          # trace-time only: counts cache misses
-            # in-place row-aligned packing into the persistent buffer: only
-            # the leaf ranges are written (fill/tail rows are permanently
-            # zero), and caller donation makes the writes allocation-free
-            # in steady state
-            for leaf, start in zip(leaves, starts):
+            # in-place tile-aligned packing into the persistent buffer:
+            # only the leaf ranges are written, and caller donation makes
+            # the writes allocation-free in steady state
+            for leaf, t0 in zip(leaves, first):
                 buf = jax.lax.dynamic_update_slice(
-                    buf, _ref.to_i32(leaf), (start,))
-            d = _ck.row_checksums(buf.reshape(nt, TILE_ROWS, LANES)) \
-                .reshape(padded_rows, 2)
-            seg = jnp.asarray(seg_ids)
-            s1 = segment_sum(d[:, 0], seg, num_segments=n_seg)
-            s2 = segment_sum(d[:, 1] + jnp.asarray(offsets) * d[:, 0],
-                             seg, num_segments=n_seg)
-            return buf, jnp.stack([s1, s2], axis=1)
+                    buf, _ref.to_i32(leaf), (int(t0) * TILE,))
+            partials = _ck.tile_checksums(buf.reshape(nt, TILE_ROWS, LANES))
+            return buf, _ck.combine(partials, first, stop)
 
         return digest
 
@@ -403,7 +389,7 @@ def clear_plan_cache() -> None:
 # On an N-device mesh the detection economics must not change: one combined
 # launch and ONE scalar host sync per step, with every device streaming only
 # its own addressable shard.  The unit of detection becomes the (leaf, shard)
-# pair: each device packs and checksums the rows of its local block, the
+# pair: each device packs and checksums the tiles of its local block, the
 # reference tables grow a leading shard dimension (n_shards, L, 2) and live
 # SHARDED over the mesh (each device compares only its own rows, on device),
 # and the only cross-device traffic on the no-fault path is the all-reduced
@@ -431,11 +417,11 @@ class ShardedDigestPlan(DigestPlan):
     """Per-shard packing layout + shard_map'd digest functions for one
     (state structure, leaf shardings, mesh) triple.
 
-    The inherited layout (``specs``/``n_rows``/row maps) is computed over
-    the LOCAL shard sizes — every device owns an identical private layout
+    The inherited layout (``specs``/tile ranges) is computed over the
+    LOCAL shard sizes — every device owns an identical private layout
     because GSPMD shard shapes are uniform — so the whole single-device
-    digest core (in-place pack + one ``row_checksums`` pallas_call
-    + exact segment-sum combine) runs unchanged INSIDE ``shard_map``, once
+    digest core (in-place pack + one ``tile_checksums`` pallas_call
+    + exact tile-range combine) runs unchanged INSIDE ``shard_map``, once
     per device, in the same single logical launch.  Global artifacts grow
     a leading shard dim, sharded over all mesh axes flattened:
 
@@ -632,12 +618,26 @@ def sharded_plan_for(tree, mesh: Mesh) -> ShardedDigestPlan:
 # ---------------------------------------------------------------------------
 # check+arm as a traceable subcomputation — the shared core of every fused
 # canary mode (DESIGN.md §4.2).  Building it resolves all host-side plan
-# state (digest-fn lookup, row index arrays, segment maps) ONCE; the
+# state (digest-fn lookup, tile ranges, arm selection) ONCE; the
 # returned function is pure and jit-embeddable, so the same subcomputation
 # serves both the standalone fused launches (core/detect.py) and the
 # in-step fused mode that runs it inside the jitted, donated training step
 # (core/fused_step.py).
 # ---------------------------------------------------------------------------
+
+def _arming(arm: Sequence[int], n_leaves: int):
+    """``(table, armed) -> table`` with rows ``arm`` replaced by the rows of
+    ``armed`` in order: a static select, not a scatter."""
+    slot = np.zeros(n_leaves, np.int32)
+    slot[list(arm)] = np.arange(len(arm), dtype=np.int32)
+    hit = np.zeros((n_leaves, 1), bool)
+    hit[list(arm)] = True
+
+    def arming(table, armed):
+        return jnp.where(hit, armed.astype(table.dtype)[slot], table)
+
+    return arming
+
 
 def check_arm_subcomputation(plan: DigestPlan, chk: Sequence[int],
                              arm: Sequence[int]):
@@ -652,8 +652,8 @@ def check_arm_subcomputation(plan: DigestPlan, chk: Sequence[int],
     digests ``leaves`` (the chk-slice leaves followed by the arm-slice
     leaves, possibly drawn from two state versions) in ONE pallas launch,
     compares the first ``len(chk)`` digests against rows ``chk`` of
-    ``ref_read`` on device, and scatter-arms the remaining digests into
-    rows ``arm`` of ``ref_write`` (in place when the caller donates it).
+    ``ref_read`` on device, and arms the remaining digests into rows
+    ``arm`` of ``ref_write`` (in place when the caller donates it).
     Pure/traceable: no host-side plan lookups survive into the traced
     path, so callers may embed ``fn`` inside their own jit — including a
     jitted step function that donates its state (core/fused_step.py).
@@ -674,15 +674,14 @@ def check_arm_subcomputation(plan: DigestPlan, chk: Sequence[int],
     union = chk + arm
     digest = plan.digest_fn(union)
     chk_rows = np.asarray(chk, np.int32)
-    arm_rows = np.asarray(arm, np.int32)
     nc = len(chk)
+    arming = _arming(arm, plan.n_leaves)
 
     def fn(buf, leaves, ref_read, ref_write):
         buf, table = digest(buf, leaves)    # ONE fused launch
         bad = jnp.any(table[:nc] != ref_read[chk_rows], axis=1) \
             if nc else jnp.zeros((0,), bool)
-        new_write = ref_write.at[arm_rows].set(table[nc:]) \
-            if arm else ref_write
+        new_write = arming(ref_write, table[nc:]) if arm else ref_write
         return buf, jnp.any(bad), bad, new_write
 
     return fn, union
@@ -693,15 +692,15 @@ def _sharded_check_arm_subcomputation(plan: ShardedDigestPlan,
                                       arm: Sequence[int]):
     """Mesh variant of ``check_arm_subcomputation`` — one shard_map whose
     body runs the per-device digest core, the on-device compare of the
-    device's own reference rows, the in-place arm scatter into the
-    device's own write rows, and the any(fault) all-reduce."""
+    device's own reference rows, the in-place arm of the device's own
+    write rows, and the any(fault) all-reduce."""
     chk = tuple(chk)
     arm = tuple(arm)
     union = chk + arm
     local_digest = plan.local_digest_fn(union)
     chk_rows = np.asarray(chk, np.int32)
-    arm_rows = np.asarray(arm, np.int32)
     nc = len(chk)
+    arming = _arming(arm, plan.n_leaves)
     axes = plan.axis_names
 
     def local_fn(buf, ref_read, ref_write, *leaves):
@@ -712,7 +711,7 @@ def _sharded_check_arm_subcomputation(plan: ShardedDigestPlan,
             if nc else jnp.zeros((0,), bool)
         # the fault flag is the only cross-device hop on the no-fault path
         flag = jax.lax.pmax(jnp.any(bad).astype(jnp.int32), axes) > 0
-        new_write = ref_write.at[0, arm_rows].set(table[nc:]) \
+        new_write = arming(ref_write[0], table[nc:])[None] \
             if arm else ref_write
         return b[None], flag, bad[None], new_write
 

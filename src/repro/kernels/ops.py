@@ -1,7 +1,7 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles: arbitrary shapes/dtypes (bit-cast + pad to tile multiples), exact
-digest recombination across rows, and pytree-level orchestration (leaf
+digest recombination across tiles, and pytree-level orchestration (leaf
 digests for whole train states).  Every kernel decides interpret vs
 compiled mode itself (``kernels.backend.interpret_mode``).
 """
@@ -37,16 +37,12 @@ def _tiles(x) -> Tuple[jnp.ndarray, int]:
 def checksum(x) -> jnp.ndarray:
     """Two-term Fletcher digest int32[2] of the raw bits of ``x``.
 
-    Row digests (s1_r, s2_r) of ``row_checksums`` combine exactly:
-        s1 = Σ_r s1_r
-        s2 = Σ_r (s2_r + offset_r · s1_r)      (mod 2^32)
+    The tile partials of ``tile_checksums`` fold into one digest with the
+    fused digest's own ``combine`` over the array's one tile range.
     """
     tiles, _ = _tiles(x)
-    d = _ck.row_checksums(tiles).reshape(-1, 2)
-    offsets = jnp.arange(d.shape[0], dtype=jnp.int32) * jnp.int32(_ck.LANES)
-    s1 = jnp.sum(d[:, 0], dtype=jnp.int32)
-    s2 = jnp.sum(d[:, 1] + offsets * d[:, 0], dtype=jnp.int32)
-    return jnp.stack([s1, s2])
+    nt = tiles.shape[0]
+    return _ck.combine(_ck.tile_checksums(tiles), [0], [nt])[0]
 
 
 @jax.jit

@@ -7,6 +7,11 @@ The detection-cost contract (DESIGN.md §4.2):
   * one canary ``check_and_arm`` = exactly 1 fused launch + 1 host sync.
 """
 
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,6 +86,89 @@ def test_subtree_checksums_subset():
         assert np.array_equal(v, full[k])
 
 
+#: leaf sizes around the tile (32768 words): one element, not a multiple
+#: of a 128-lane row, exactly one tile, several tiles with a ragged tail
+TILE_SIZES = [1, 300, dg.TILE, 3 * dg.TILE + 517]
+
+
+@pytest.mark.parametrize("size", TILE_SIZES)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32, jnp.int8])
+def test_tile_aligned_digest_is_bit_exact(size, dtype):
+    """Each leaf's digest, packed at tile alignment beside neighbours and
+    folded from its tiles' lane partials, equals the plain oracle and the
+    host digest of its bytes."""
+    k = jax.random.fold_in(KEY, size)
+    leaf = (jax.random.normal(k, (size,)) * 50).astype(dtype)
+    tree = {"a": jnp.arange(5, dtype=jnp.int32), "leaf": leaf,
+            "z": jax.random.normal(KEY, (dg.TILE + 1,))}
+    plan = dg.plan_for(tree)
+    table = np.asarray(plan.digest_table(tree))
+    for i, key in enumerate(plan.keys):
+        x = _leaves_by_key(tree)[key]
+        assert np.array_equal(table[i], np.asarray(ref.checksum_ref(x))), key
+        assert np.array_equal(table[i], dg.host_checksum(np.asarray(x))), key
+    assert np.array_equal(np.asarray(ops.checksum(leaf)), table[1])
+
+
+def test_many_small_leaves_bit_exact():
+    """Hundreds of sub-tile leaves of mixed dtypes: one tile each, every
+    digest exact, and the buffer one tile per leaf."""
+    dtypes = (jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32)
+    rng = np.random.default_rng(7)
+    tree = {f"l{i:03d}": jnp.asarray(rng.normal(size=1 + (37 * i) % 700) * 9,
+                                     dtype=dtypes[i % 4])
+            for i in range(160)}
+    plan = dg.plan_for(tree)
+    assert plan.n_tiles == 160
+    table = np.asarray(plan.digest_table(tree))
+    for i, key in enumerate(plan.keys):
+        assert np.array_equal(table[i], dg.host_checksum(
+            np.asarray(tree[key]))), key
+
+
+def test_check_arm_union_digests_input_and_output_leaves():
+    """The K=1 check-and-arm union packs every leaf of the input state and
+    every leaf of the output state in one buffer: the check half compares
+    the input's digests, the arm half writes the output's, both exact."""
+    tree = _tree()
+    new = jax.tree_util.tree_map(lambda x: x + jnp.ones_like(x), tree)
+    plan = dg.plan_for(tree)
+    idx = list(range(plan.n_leaves))
+    core, union = dg.check_arm_subcomputation(plan, idx, idx)
+    assert union == tuple(idx) * 2
+    fn = jax.jit(lambda b, a, n, r, w: core(
+        b, plan.leaves(a) + plan.leaves(n), r, w), donate_argnums=(0, 4))
+    host_new = np.stack([dg.host_checksum(np.asarray(_leaves_by_key(new)[k]))
+                         for k in plan.keys])
+    ref_in = plan.digest_table(tree)
+    buf, flag, bad, armed = fn(plan.take_buffer(union), tree, new, ref_in,
+                               jnp.zeros_like(ref_in))
+    assert not bool(flag) and not np.asarray(bad).any()
+    assert np.array_equal(np.asarray(armed), host_new)
+    # a stale reference row fires the flag on exactly that leaf
+    m = plan.index_of("opt/m")
+    stale = plan.digest_table(tree).at[m, 0].add(1)
+    buf, flag, bad, armed = fn(buf, tree, new, stale,
+                               jnp.zeros_like(ref_in))
+    assert bool(flag)
+    assert np.flatnonzero(np.asarray(bad)).tolist() == [m]
+    assert np.array_equal(np.asarray(armed), host_new)
+
+
+def test_canary_program_has_no_scatter():
+    """The fused check-and-arm program folds tiles and arms rows without a
+    scatter."""
+    tree = _tree()
+    plan = dg.plan_for(tree)
+    idx = list(range(plan.n_leaves))
+    core, union = dg.check_arm_subcomputation(plan, idx, idx)
+    table = plan.digest_table(tree)
+    text = jax.jit(lambda b, a, r, w: core(
+        b, plan.leaves(a) + plan.leaves(a), r, w)).lower(
+        plan.take_buffer(union), tree, table, table).as_text()
+    assert "scatter" not in text
+
+
 # ---------------------------------------------------------------------------
 # attribution
 # ---------------------------------------------------------------------------
@@ -97,6 +185,24 @@ def test_flip_in_any_leaf_attributed_to_exactly_that_leaf():
             [flip_bit(x, 0, bit) if i == j else x
              for i, (_, x) in enumerate(flat)])
         assert ops.verify_tree(corrupted, reference) == [key]
+
+
+@pytest.mark.parametrize("where", ["first", "last"])
+def test_flip_at_tile_edge_attributed_to_exactly_that_leaf(where):
+    """A flip in the first or the last word of a tile moves only the digest
+    of the leaf that owns the tile, in every leaf of several tiles."""
+    k = jax.random.split(KEY, 3)
+    tree = {"a": jax.random.normal(k[0], (2 * dg.TILE + 9,)),
+            "b": jax.random.normal(k[1], (3 * dg.TILE,)).astype(jnp.bfloat16),
+            "c": jax.random.normal(k[2], (dg.TILE,))}
+    reference = ops.tree_checksums(tree)
+    for key in tree:
+        n = tree[key].size
+        for t in range(-(-n // dg.TILE)):
+            e = t * dg.TILE if where == "first" \
+                else min((t + 1) * dg.TILE, n) - 1
+            bad = dict(tree, **{key: flip_bit(tree[key], e, 5)})
+            assert ops.verify_tree(bad, reference) == [key], (key, e)
 
 
 def test_canary_names_dormant_flip_in_armed_window():
@@ -471,3 +577,76 @@ def test_mesh_snapshots_keep_host_digests():
     snap.state = dict(snap.state, opt={"m": rotted})
     assert micro.verify(snap, tree) == ["opt/m"]
     assert dg.STATS.snapshot()[:2] == (0, 0)
+
+
+# ---------------------------------------------------------------------------
+# the same combine, per device, on a mesh (8 CPU devices in a child process:
+# the test session keeps one device)
+# ---------------------------------------------------------------------------
+
+SHARDED_PROG = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import sys
+    sys.path.insert(0, "src")
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.faults import flip_bit
+    from repro.kernels import digest as dg
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((4, 2), ("data", "model"))
+    k = jax.random.split(jax.random.PRNGKey(3), 4)
+    host = {"w": jax.random.normal(k[0], (256, 300)),
+            "e": jax.random.normal(k[1], (8, dg.TILE + 10)).astype(
+                jnp.bfloat16),
+            "b": jax.random.randint(k[2], (300,), -9, 9).astype(jnp.int8),
+            "s": jnp.int32(7)}
+    specs = {"w": P("data", "model"), "e": P("data", None), "b": P(),
+             "s": P()}
+    put = lambda t: {n: jax.device_put(x, NamedSharding(mesh, specs[n]))
+                     for n, x in t.items()}
+    tree = put(host)
+    plan = dg.sharded_plan_for(tree, mesh)
+    assert isinstance(plan, dg.ShardedDigestPlan)
+    assert plan.n_shards == 8
+    table = np.asarray(plan.digest_table(tree))
+    assert table.shape == (8, plan.n_leaves, 2)
+    for i, key in enumerate(plan.keys):
+        assert np.array_equal(table[:, i],
+                              dg.host_shard_checksums(tree[key])), key
+    # check the input, arm the output, in one shard_map'd launch
+    new = put({n: x + jnp.ones_like(x) for n, x in host.items()})
+    idx = list(range(plan.n_leaves))
+    core, union = dg.check_arm_subcomputation(plan, idx, idx)
+    fn = jax.jit(lambda b, a, n, r, w: core(
+        b, plan.leaves(a) + plan.leaves(n), r, w))
+    ref = plan.digest_table(tree)
+    buf, flag, bad, armed = fn(plan.take_buffer(union), tree, new, ref, ref)
+    assert not bool(flag) and not np.asarray(bad).any()
+    want = np.stack([dg.host_shard_checksums(new[key]) for key in plan.keys],
+                    axis=1)
+    assert np.array_equal(np.asarray(armed), want)
+    # a flip in the first word of shard 5's block of "w" names (5, "w")
+    j = plan.index_of("w")
+    w = np.array(host["w"])
+    r0, c0 = [sl.start or 0 for sl in dg.shard_indices(tree["w"])[5]]
+    w[r0, c0] = np.asarray(flip_bit(jnp.float32(w[r0, c0]), 0, 9))
+    hit = put(dict(host, w=jnp.asarray(w)))
+    buf, flag, bad, _ = fn(buf, hit, new, ref, ref)
+    assert bool(flag)
+    assert np.argwhere(np.asarray(bad)).tolist() == [[5, j]]
+    print("SHARDED_OK")
+""")
+
+
+def test_sharded_plan_reuses_the_combine_on_an_8_device_mesh():
+    """``ShardedDigestPlan`` runs the single-device digest core per device:
+    every (shard, leaf) digest equals the host digest of that shard's
+    bytes, the fused check-and-arm is exact, and a flip names its shard."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", SHARDED_PROG],
+                         capture_output=True, text=True, cwd=repo,
+                         timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SHARDED_OK" in out.stdout

@@ -52,23 +52,21 @@ def test_checksum_detects_swap():
                               np.asarray(ops.checksum(y)))
 
 
-@pytest.mark.parametrize("nt", [1, 2, 3, 5])
+@pytest.mark.parametrize("nt", [1, 2, 3, 5, 8, 13])
 def test_gridded_row_checksums_match_batch_kernel_and_host(nt):
     """The gridded kernel is what compiles on TPU; interpreted here at
-    small ``nt`` it must agree row for row with the batch kernel the CPU
-    runs, and its rows must combine into the host oracle's digest."""
+    small ``nt`` (one partial block, whole blocks, and a ragged last
+    block) it must agree lane for lane with the one-block kernel the CPU
+    runs, and its tiles must combine into the host oracle's digest."""
     from repro.kernels import checksum as ck
     from repro.kernels.digest import host_checksum
     x = jax.random.randint(jax.random.PRNGKey(nt), (nt, ck.TILE_ROWS,
                            ck.LANES), -2**31, 2**31 - 1, dtype=jnp.int32)
-    grid = ck._gridded_row_checksums(x, interpret=True)
+    grid = ck._gridded_tile_checksums(x, interpret=True)
+    assert grid.shape == (2, nt, ck.LANES)
     assert np.array_equal(np.asarray(grid),
-                          np.asarray(ck.row_checksums(x, interpret=True)))
-    d = grid.reshape(-1, 2)
-    offsets = jnp.arange(d.shape[0], dtype=jnp.int32) * jnp.int32(ck.LANES)
-    combined = jnp.stack([jnp.sum(d[:, 0], dtype=jnp.int32),
-                          jnp.sum(d[:, 1] + offsets * d[:, 0],
-                                  dtype=jnp.int32)])
+                          np.asarray(ck.tile_checksums(x, interpret=True)))
+    combined = ck.combine(grid, [0], [nt])[0]
     assert np.array_equal(np.asarray(combined),
                           host_checksum(np.asarray(x)))
 

@@ -82,15 +82,23 @@ def _compiled_text(fn, *args, donate=()):
 
 
 def test_full_state_digest_compiles(kernels_compiled, full_state, one_chip):
-    """In-place pack + ``row_checksums`` over a K=1 canary slice: every
-    leaf of the full-width train state in one donated packing buffer."""
+    """In-place pack + ``tile_checksums`` over a K=1 canary slice: every
+    leaf of the full-width train state in one donated packing buffer.
+    The tiles fold into leaves with no scatter, and the program's
+    temporaries stay far below the packed buffer: the kernel's output is
+    lane-dense, 1/128 of what it reads."""
     from repro.kernels.digest import plan_for
     plan = plan_for(full_state)
     leaves = plan.leaves(full_state)
     buf = jax.ShapeDtypeStruct((plan.n_tiles * ck.TILE,), jnp.int32,
                                sharding=one_chip)
-    text = _compiled_text(plan.digest_fn(), buf, leaves, donate=(0,))
+    compiled = jax.jit(plan.digest_fn(), donate_argnums=(0,)).lower(
+        buf, leaves).compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert "scatter" not in text
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < plan.bytes_per_pass // 8, (temps, plan.bytes_per_pass)
 
 
 def test_parity_update_compiles(kernels_compiled, full_state, one_chip):
@@ -140,6 +148,6 @@ def test_smoke_kernel_audit_reads_compiled_kernels(kernels_compiled,
     x = jax.ShapeDtypeStruct((2, ck.TILE_ROWS, ck.LANES), jnp.int32,
                              sharding=one_chip)
     with cs.kernel_audit() as audit:
-        jax.jit(ck.row_checksums).lower(x).compile()
+        jax.jit(ck.tile_checksums).lower(x).compile()
     assert cs.check_kernels(audit, {"_row_checksum_kernel"}, "v5e")
     assert not audit["interpreted"]
